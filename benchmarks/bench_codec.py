@@ -27,8 +27,8 @@ import numpy as np
 from repro.compression.gd import gd_compress, gd_decompress
 from repro.compression.greedy_gd import greedy_gd_compress
 from repro.core import pipeline, transforms as T
-from repro.core.float_bits import normalize_to_binade
-from repro.core.lossless import significand_int
+from repro.core.float_bits import F64, normalize_bits
+from repro.core.lossless import significand_from_bits
 from repro.data import gas_turbine_emissions
 
 # anchored to the repo root so the tracked baseline updates regardless of cwd;
@@ -77,8 +77,8 @@ def _record(rows, name, us, derived="", nbytes=None):
 def bench_transforms(rows: list, n_elems: int = 100_000):
     tag = f"{n_elems // 1000}k"
     x = gas_turbine_emissions(n_elems)
-    y, e, s = normalize_to_binade(jnp.asarray(x))
-    X = significand_int(y)
+    y, e, s = normalize_bits(x.view(np.uint64), F64)
+    X = significand_from_bits(y, F64)
     for name, fn in [
         ("compact_bins", lambda: T.compact_bins_forward(X, 16)),
         ("multiply_shift", lambda: T.multiply_shift_forward(X, 2, max_iter=64)),
@@ -364,9 +364,11 @@ def bench_streaming(rows: list, n_elems: int = 100_000):
         "print(json.dumps({'us': us, 'rss_delta': rss1 - rss0,\n"
         "                  'budget': budget}))\n"
     )
+    # the child measures host memory only: it stays off the accelerator,
+    # which belongs to this process
     r = subprocess.run([sys.executable, "-c", child, str(logical)],
                        capture_output=True, text=True, timeout=600,
-                       env=dict(os.environ))
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, f"4x-budget child failed:\n{r.stderr}"
     stats = json.loads(r.stdout.strip().splitlines()[-1])
     assert stats["rss_delta"] < stats["budget"], (

@@ -149,10 +149,18 @@ def _harness_worker(args):
     }
 
 
+def _pin_cpu():
+    """Pool initializer: keep a worker off the accelerator, which belongs
+    to the parent process (one process per chip)."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def bench_step_harness(rows: list, smoke: bool = False):
     """n-workers x bucket-size grid, each worker a separate *spawned*
-    process (jax + fork is unsafe).  Gates end-to-end steady step time and
-    plan-cache hit rate per grid point."""
+    process (jax + fork is unsafe) pinned to the CPU backend.  Gates
+    end-to-end steady step time and plan-cache hit rate per grid point."""
     import multiprocessing as mp
 
     ctx = mp.get_context("spawn")
@@ -163,14 +171,15 @@ def bench_step_harness(rows: list, smoke: bool = False):
     for workers, n_elems, steps in grid:
         argv = [(100 + w, n_elems, steps) for w in range(workers)]
         t0 = time.time()
-        with ctx.Pool(workers) as pool:
+        with ctx.Pool(workers, initializer=_pin_cpu) as pool:
             res = pool.map(_harness_worker, argv)
         wall_s = time.time() - t0
         tag = f"w{workers}_{n_elems // 1024}k"
         us = float(np.mean([r["us"] for r in res]))
         hits = sum(r["hits"] for r in res)
         _record(rows, f"step_harness_{tag}", us,
-                f"hits={hits} steps={steps}/worker wall={wall_s:.1f}s",
+                f"cpu workers hits={hits} steps={steps}/worker "
+                f"wall={wall_s:.1f}s",
                 n_elems * 4)
         _counts[f"step_harness_hits_{tag}"] = hits
         _counts[f"step_harness_reselects_steady_{tag}"] = sum(

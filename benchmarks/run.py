@@ -13,6 +13,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized codec pass (10k elements, no model benches)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     rows = []
     if args.only in (None, "paper"):
         from benchmarks import bench_paper

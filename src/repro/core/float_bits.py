@@ -118,15 +118,19 @@ def mantissa(x, spec: FloatSpec | None = None):
     return to_bits(x, spec) & spec.uint_dtype(spec.man_mask)
 
 
-def compose(sign, biased_exp, man, spec: FloatSpec):
-    """Assemble (S, E, M) fields into a float (inverse of the accessors)."""
+def compose_bits(sign, biased_exp, man, spec: FloatSpec):
+    """Assemble (S, E, M) fields into the format's unsigned bit word."""
     u = spec.uint_dtype
-    b = (
+    return (
         (sign.astype(u) << spec.sign_shift)
         | ((biased_exp.astype(u) & u(spec.exp_mask)) << spec.man_bits)
         | (man.astype(u) & u(spec.man_mask))
     )
-    return from_bits(b, spec)
+
+
+def compose(sign, biased_exp, man, spec: FloatSpec):
+    """Assemble (S, E, M) fields into a float (inverse of the accessors)."""
+    return from_bits(compose_bits(sign, biased_exp, man, spec), spec)
 
 
 # ---------------------------------------------------------------------------
@@ -190,19 +194,30 @@ def next_float(x, spec: FloatSpec | None = None):
 ZERO_EXP_SENTINEL = -(1 << 14)  # exponent marker for exact zeros
 
 
-def normalize_to_binade(x, spec: FloatSpec | None = None):
+def _top_bit(v):
+    """Index of the highest set bit of a positive int64 below 2^63, from
+    32-bit count-leading-zeros of its two halves (integer ops only)."""
+    hi = (v >> 32).astype(jnp.uint32)
+    lo = v.astype(jnp.uint32)
+    return jnp.where(hi != 0, 63 - lax.clz(hi).astype(jnp.int32),
+                     31 - lax.clz(lo).astype(jnp.int32))
+
+
+def normalize_bits(b, spec: FloatSpec):
     """Map every finite sample to [1, 2) by exact 2^-e scaling — pure bit ops.
 
-    Returns (y, exponents, signs).  y = |x| / 2^e in [1,2); exponents (int32)
-    and signs (uint32) are the per-sample metadata the paper mentions in §3
-    ("storing as metadata the information on the original exponent of each
-    sample").  Implemented entirely in the bit domain because XLA:CPU flushes
-    subnormals to zero in float arithmetic (DAZ/FTZ) — integer ops are exact.
-    Zeros map to (1.0, ZERO_EXP_SENTINEL) and survive the round-trip.
+    ``b`` holds the samples' unsigned bit words (the host takes them for
+    free with ``ndarray.view``).  Returns (y, exponents, signs): y is the
+    bit word of |x| / 2^e in [1,2); exponents (int32) and signs (uint32)
+    are the per-sample metadata the paper mentions in §3 ("storing as
+    metadata the information on the original exponent of each sample").
+    Everything stays in the integer domain: XLA:CPU flushes subnormals to
+    zero in float arithmetic (DAZ/FTZ), and the TPU compiler accepts no
+    bitcast between f64 and u64.  Zeros map to (1.0, ZERO_EXP_SENTINEL)
+    and survive the round-trip.
     """
-    spec = spec or spec_for(x)
     u = spec.uint_dtype
-    b = to_bits(x, spec)
+    b = jnp.asarray(b, u)
     s = (b >> spec.sign_shift).astype(jnp.uint32)
     man = (b & u(spec.man_mask)).astype(jnp.int64)
     be = ((b >> spec.man_bits) & u(spec.exp_mask)).astype(jnp.int32)
@@ -211,8 +226,7 @@ def normalize_to_binade(x, spec: FloatSpec | None = None):
     is_sub = (man != 0) & (be == 0)
 
     # subnormal: value = man * 2^(1-bias-l); top set bit h gives e
-    # (int->float conversion is exact for man < 2^(l+1) and FTZ-immune)
-    h = unbiased_exponent(man.astype(jnp.float64), F64).astype(jnp.int32)
+    h = _top_bit(jnp.maximum(man, 1))
     sub_e = h + (1 - spec.bias - spec.man_bits)
     sub_man = (man << (spec.man_bits - h).astype(jnp.int64)) & jnp.int64(spec.man_mask)
 
@@ -220,16 +234,15 @@ def normalize_to_binade(x, spec: FloatSpec | None = None):
     e = jnp.where(is_zero, ZERO_EXP_SENTINEL, e).astype(jnp.int32)
     out_man = jnp.where(is_sub, sub_man, man)
     out_man = jnp.where(is_zero, 0, out_man)
-    y = from_bits((u(spec.bias) << spec.man_bits) | out_man.astype(u), spec)
+    y = (u(spec.bias) << spec.man_bits) | out_man.astype(u)
     return y, e, s
 
 
-def denormalize_from_binade(y, exponents, signs, spec: FloatSpec | None = None):
-    """Exact inverse of :func:`normalize_to_binade` — pure bit ops."""
-    spec = spec or spec_for(y)
+def denormalize_bits(y, exponents, signs, spec: FloatSpec):
+    """Exact inverse of :func:`normalize_bits`, on bit words."""
     u = spec.uint_dtype
     e = jnp.asarray(exponents, jnp.int32)
-    man = (to_bits(y, spec) & u(spec.man_mask)).astype(jnp.int64)
+    man = (jnp.asarray(y, u) & u(spec.man_mask)).astype(jnp.int64)
 
     is_zero = e == ZERO_EXP_SENTINEL
     is_sub = (~is_zero) & (e < (1 - spec.bias))
@@ -241,5 +254,4 @@ def denormalize_from_binade(y, exponents, signs, spec: FloatSpec | None = None):
 
     bits = jnp.where(is_sub, sub_bits, normal_bits)
     bits = jnp.where(is_zero, 0, bits).astype(u)
-    bits = bits | (jnp.asarray(signs).astype(u) << spec.sign_shift)
-    return from_bits(bits, spec)
+    return bits | (jnp.asarray(signs).astype(u) << spec.sign_shift)

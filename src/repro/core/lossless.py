@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from .float_bits import FloatSpec, F64, mantissa, spec_for, to_bits
+from .float_bits import FloatSpec, F64, compose_bits, mantissa, spec_for, to_bits
 
 
 # ---------------------------------------------------------------------------
@@ -126,26 +126,25 @@ def mul_pow2_is_exact(x, k: int, spec: FloatSpec | None = None):
 # unified integer-significand view (used by the transforms)
 # ---------------------------------------------------------------------------
 
-def significand_int(x, e_star: int = 0, spec: FloatSpec | None = None):
-    """X = x / 2^(e*-l) as integer, for x in binade e* (|x| in [2^e*, 2^{e*+1})).
+def significand_from_bits(b, spec: FloatSpec = F64):
+    """X = x / 2^(e*-l) as integer, for the bit words ``b`` of x in binade
+    e* (|x| in [2^e*, 2^{e*+1})).
 
     X is in [2^l, 2^{l+1}).  The transforms do all their arithmetic on X
     (exact by construction); see module docstring.
     """
-    spec = spec or spec_for(x)
-    man = mantissa(x, spec).astype(jnp.int64)
-    return man + (jnp.int64(1) << spec.man_bits)
+    man = (jnp.asarray(b, spec.uint_dtype) & spec.uint_dtype(spec.man_mask))
+    return man.astype(jnp.int64) + (jnp.int64(1) << spec.man_bits)
 
 
-def from_significand_int(X, e_star, spec: FloatSpec = F64):
-    """Inverse of :func:`significand_int`, with per-element binade e_star.
+def significand_to_bits(X, e_star, spec: FloatSpec = F64):
+    """Inverse of :func:`significand_from_bits`, with per-element binade
+    e_star: the bit words of the float with significand X at binade e_star.
 
-    X in [2^l, 2^{l+1}) (int64), e_star int32 array or scalar: returns the
-    float with significand X at binade e_star.
-    """
-    from .float_bits import compose
-
+    X in [2^l, 2^{l+1}) (int64), e_star int32 array or scalar.  The codec's
+    device programs stop at the bit words; the float view is taken on the
+    host (``ndarray.view``)."""
     X = jnp.asarray(X, jnp.int64)
     e = jnp.asarray(e_star, jnp.int32)
     man = (X - (jnp.int64(1) << spec.man_bits)).astype(spec.uint_dtype)
-    return compose(jnp.uint32(0), e + spec.bias, man, spec)
+    return compose_bits(jnp.uint32(0), e + spec.bias, man, spec)

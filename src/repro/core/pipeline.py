@@ -53,12 +53,11 @@ from .float_bits import (
     F32,
     F64,
     FloatSpec,
-    denormalize_from_binade,
-    normalize_to_binade,
+    denormalize_bits,
+    normalize_bits,
     spec_for,
-    unbiased_exponent,
 )
-from .lossless import from_significand_int, significand_int
+from .lossless import significand_from_bits, significand_to_bits
 
 SPECS = {"f64": F64, "f32": F32, "bf16": BF16, "f16": F16}
 
@@ -177,11 +176,11 @@ def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
         e = min(s + chunk_elems, n)
         Xr = inv(Xt[s:e], off[s:e], _slice_meta(meta, s, e), spec=spec)
         ok = ok & jnp.all(Xr == X[s:e])
-    vals = from_significand_int(Xt, off.astype(jnp.int32), spec)
+    vals = significand_to_bits(Xt, off.astype(jnp.int32), spec)
     vals_np, ok_np = jax.device_get((vals, ok))
     if not bool(ok_np):
         return None
-    return vals_np, meta
+    return vals_np.view(spec.float_dtype), meta
 
 
 # ---------------------------------------------------------------------------
@@ -263,7 +262,7 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             a_base = top - x_min - j * jnp.int64(w_eff)
             A = a_base + (a_base & 1) + parity.astype(jnp.int64)
             ok &= jnp.all((Y << 1) - A == X)
-            vals = from_significand_int(Y, jnp.ones(Y.shape, jnp.int32), spec)
+            vals = significand_to_bits(Y, jnp.ones(Y.shape, jnp.int32), spec)
             return (ok, vals) + entropy(val_bytes(vals)) + (x_min, j, parity,
                                                             j_max)
 
@@ -283,8 +282,8 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             bin_id = (jnp.searchsorted(thr, Xt, side="right") if k > 1
                       else jnp.zeros(Xt.shape, jnp.int64))
             ok &= fits & jnp.all(Xt - shifts[bin_id] == X)
-            vals = from_significand_int(Xt, jnp.zeros(Xt.shape, jnp.int32),
-                                        spec)
+            vals = significand_to_bits(Xt, jnp.zeros(Xt.shape, jnp.int32),
+                                       spec)
             return (ok, vals) + entropy(val_bytes(vals)) + (shifts, thr)
 
         return run_cb
@@ -371,7 +370,8 @@ def _fused_encode(prep: "_Prepared", name: str, p: dict) -> Encoded | None:
             e_star=0, shifts=np.asarray(shifts, np.int64),
             thresholds=np.asarray(thr, np.int64),
         )
-    enc = prep.finish(name, dict(p), np.asarray(vals), meta)
+    enc = prep.finish(name, dict(p), np.asarray(vals).view(spec.float_dtype),
+                      meta)
     enc.payload = _fused_frame(lanes, n_bytes, freq, b0, b1, e0, e1, x)
     enc.payload_backend = "rans"
     return enc
@@ -424,7 +424,6 @@ class _Prepared:
     spec: FloatSpec
     finite: np.ndarray          # bool[n]: element goes through the transform
     pass_mask: np.ndarray       # ~finite
-    active: object              # jax array of transformable values
     X: object | None            # int64 significands (None when no active)
     exps_np: np.ndarray
     signs_np: np.ndarray
@@ -471,15 +470,15 @@ class _Prepared:
 
 
 def _prepare(x, spec: FloatSpec | None = None) -> _Prepared:
-    x = jnp.asarray(x)
-    spec = spec or spec_for(x)
     xf = np.asarray(x).reshape(-1)
+    spec = spec or spec_for(xf)
     finite = np.isfinite(xf.astype(np.float64)) & (xf != 0)
     pass_mask = ~finite
-    active = jnp.asarray(xf[finite])
+    # the device sees bit words only: the float view is the host's
+    active = xf[finite].view(spec.uint_dtype)
     if active.shape[0]:
-        y01, exps, signs = normalize_to_binade(active, spec)
-        X = significand_int(y01, 0, spec)
+        y01, exps, signs = normalize_bits(jnp.asarray(active), spec)
+        X = significand_from_bits(y01, spec)
         exps_np = np.asarray(exps, np.int64)
         signs_np = np.asarray(signs, np.uint8)
     else:
@@ -488,7 +487,7 @@ def _prepare(x, spec: FloatSpec | None = None) -> _Prepared:
         signs_np = np.zeros(0, np.uint8)
     return _Prepared(
         xf=xf, shape=np.shape(x), spec=spec, finite=finite,
-        pass_mask=pass_mask, active=active, X=X, exps_np=exps_np,
+        pass_mask=pass_mask, X=X, exps_np=exps_np,
         signs_np=signs_np,
     )
 
@@ -1064,7 +1063,7 @@ def _select_analytic(
                 Xt, off, meta = fwd(Xs, spec=spec, extrema=extrema, **p)
             except T.TransformError:
                 continue
-            vals = from_significand_int(Xt, off.astype(jnp.int32), spec)
+            vals = significand_to_bits(Xt, off.astype(jnp.int32), spec)
             data_bytes = np.asarray(vals).tobytes()
             meta_cost = _scaled_meta_bytes(meta, scale)
         exact.append(
@@ -1105,7 +1104,8 @@ def _select_exact(xf, finite, X, spec, candidates, size_fn, common_meta):
             continue
         if not bool(jnp.all(Xr == X)):
             continue  # reject candidates that do not round-trip, never ship
-        vals = np.asarray(from_significand_int(Xt, off.astype(jnp.int32), spec))
+        vals = np.asarray(significand_to_bits(
+            Xt, off.astype(jnp.int32), spec)).view(spec.float_dtype)
         data = xf.copy()
         data[finite] = vals
         score = size_fn(data.tobytes()) + _meta_bytes(meta) + common_meta
@@ -1132,15 +1132,16 @@ def decode(enc: Encoded) -> np.ndarray:
     from ..compression.bitplane import decompress_int_stream
 
     pass_mask = _unpack_z(enc.passthrough_z, n).astype(bool)
-    if enc.n_active:
-        active = jnp.asarray(flat[~pass_mask])
-        exps = decompress_int_stream(enc.exponents_z, enc.n_active).astype(np.int32)
-        signs = _unpack_z(enc.signs_z, enc.n_active)
-        off = unbiased_exponent(active, spec)    # transform landed at binade `off`
-        Xt = significand_int(active, 0, spec)
-        _, inv = T.TRANSFORMS[enc.method]
-        X = inv(Xt, off.astype(jnp.int32), enc.meta, spec=spec)
-        y01 = from_significand_int(X, jnp.zeros_like(off, jnp.int32), spec)
-        vals = denormalize_from_binade(y01, jnp.asarray(exps), jnp.asarray(signs), spec)
-        out[~pass_mask] = np.asarray(vals)
+    exps = decompress_int_stream(enc.exponents_z, enc.n_active).astype(np.int32)
+    signs = _unpack_z(enc.signs_z, enc.n_active)
+    # bit words in, bit words out: the float views are taken on the host
+    b = jnp.asarray(flat[~pass_mask].view(spec.uint_dtype))
+    # the transform landed each value at binade `off`
+    off = ((b >> spec.man_bits) & spec.uint_dtype(spec.exp_mask)).astype(
+        jnp.int32) - spec.bias
+    _, inv = T.TRANSFORMS[enc.method]
+    X = inv(significand_from_bits(b, spec), off, enc.meta, spec=spec)
+    y01 = significand_to_bits(X, jnp.zeros_like(off), spec)
+    vals = denormalize_bits(y01, exps, signs, spec)
+    out[~pass_mask] = np.asarray(vals).view(spec.float_dtype)
     return out.reshape(np.shape(enc.data))

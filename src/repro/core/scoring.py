@@ -54,7 +54,8 @@ from ..kernels.scoregrid.ops import (
     plane_byte_stats_grid,
 )
 from ..kernels.sharedbits.ops import plane_stats_u64
-from .float_bits import FloatSpec, to_bits
+from .float_bits import FloatSpec
+from .lossless import significand_to_bits
 
 # on TPU the stacked estimator runs the compiled Pallas scoregrid kernel;
 # on CPU its batched-jnp twin fuses into the same stacked dispatch
@@ -191,12 +192,9 @@ def estimate_stream_bits(words) -> float:
 @functools.partial(jax.jit, static_argnames=("spec",))
 def score_significands(Xt, off, spec: FloatSpec) -> jnp.ndarray:
     """Fused compose+score: significands/offsets -> estimated bits, one
-    dispatch per candidate (float composition, bitcast, plane stats and
-    byte histogram all inside a single jit)."""
-    from .lossless import from_significand_int
-
-    vals = from_significand_int(Xt, jnp.asarray(off, jnp.int32), spec)
-    w = to_bits(vals, spec).astype(jnp.uint64)
+    dispatch per candidate (bit-word composition, plane stats and byte
+    histogram all inside a single jit)."""
+    w = _candidate_words(Xt, off, spec)
     return _estimate_words(w, lanes=spec.width // 8)
 
 
@@ -241,10 +239,8 @@ def _bit_length(v):
 def _candidate_words(Xt, off, spec: FloatSpec):
     """Compose a candidate's (significands, binade offsets) into the uint64
     word stream the analytic estimator consumes."""
-    from .lossless import from_significand_int
-
-    vals = from_significand_int(Xt, jnp.asarray(off, jnp.int32), spec)
-    return to_bits(vals, spec).astype(jnp.uint64)
+    return significand_to_bits(Xt, jnp.asarray(off, jnp.int32),
+                               spec).astype(jnp.uint64)
 
 
 def _sse_build(X, x_min, w_eff, top, spec: FloatSpec):

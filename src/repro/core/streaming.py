@@ -228,7 +228,7 @@ class WindowPlanner:
                 )
             return pipeline.apply_transform(chunk, name, prm, spec=self._spec,
                                             backend=self._backend)
-        except Exception:
+        except T.TransformError:
             if not self._fallback_identity:
                 raise
             # picked transform rejected this chunk's data: lossless fallback

@@ -76,6 +76,12 @@ class MultimodalStream:
             "labels": b["labels"][:, : self.seq - p],
         }
 
+    def batches(self, start_step: int = 0):
+        step = start_step
+        while True:
+            yield step, self.batch_at(step)
+            step += 1
+
 
 def stream_for(cfg, batch: int, seq: int, seed: int = 0):
     if cfg.family == "encdec":

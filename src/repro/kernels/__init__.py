@@ -19,8 +19,9 @@ jit'd wrapper (ops.py) and a pure-jnp oracle (ref.py):
   the normative numpy spec).
 
 All kernels run in interpret mode on CPU (validated against ref.py in
-tests/test_kernels.py / tests/test_rans.py) and compile for TPU as the
-target.
+tests/test_kernels.py / tests/test_rans.py).  On a TPU they are compiled;
+tests/test_tpu_compile.py compiles scoregrid and the rANS histogram for a
+described v5e.
 """
 import jax
 

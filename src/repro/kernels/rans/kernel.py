@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..scoregrid.kernel import byte_hist_block
 from .ref import MAX_RENORM, PROB_BITS, PROB_SCALE, RANS_L
 
 ROWS = 8        # uint32 sublanes per histogram grid step (int32 min tile)
@@ -45,13 +46,9 @@ _BLK = ROWS * 128
 
 def _hist_kernel(x_ref, out_ref):
     i = pl.program_id(0)
-    x = x_ref[...]                        # (ROWS, 128) uint32
-    vals = lax.broadcasted_iota(jnp.int32, (ROWS, 128, 256), 2)
-    hist = jnp.zeros((256,), jnp.int32)
-    for b in range(4):
-        by = ((x >> jnp.uint32(8 * b)) & jnp.uint32(0xFF)).astype(jnp.int32)
-        hist = hist + (by[:, :, None] == vals).sum((0, 1), dtype=jnp.int32)
-    blk = jnp.stack([hist[:128], hist[128:]])
+    bins = (lax.broadcasted_iota(jnp.int32, (2, 128), 0) * 128
+            + lax.broadcasted_iota(jnp.int32, (2, 128), 1))
+    blk = byte_hist_block(x_ref[...], jnp.zeros((2, 128), jnp.int32), bins)
 
     @pl.when(i == 0)
     def _init():
@@ -68,8 +65,8 @@ def _hist_blocks(x3: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
     return pl.pallas_call(
         _hist_kernel,
         grid=(x3.shape[0] // ROWS,),
-        in_specs=[pl.BlockSpec((ROWS, 128), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((2, 128), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((ROWS, 128), lambda i: (i, jnp.int32(0)))],
+        out_specs=pl.BlockSpec((2, 128), lambda i: (jnp.int32(0), jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((2, 128), jnp.int32),
         interpret=interpret,
     )(x3)

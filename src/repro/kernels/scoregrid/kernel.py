@@ -36,31 +36,48 @@ ROWS = 8        # words-tile sublanes per grid step (int32 min tile height)
 OUT_ROWS = 4    # ones | transitions | hist[:128] | hist[128:]
 
 
+def _total(v):
+    """Sum of an int32 tile as a ``(1, 1)`` array (2-D reductions only:
+    Mosaic lowers neither 1-D vectors nor 3-D reductions)."""
+    col = jnp.sum(v, axis=0, keepdims=True, dtype=jnp.int32)
+    return jnp.sum(col, axis=1, keepdims=True, dtype=jnp.int32)
+
+
+def byte_hist_block(x, blk, bins):
+    """Add the 256-bin histogram of the four bytes of every uint32 word of
+    ``x`` into ``blk``: the count of byte value ``v`` lands in the cells
+    where ``bins == v`` (cells with a negative bin are left as they are).
+
+    The output block is written through ``jnp.where`` masks over iotas, not
+    ``.at[].set`` (Mosaic has no scatter), and the byte planes stay a
+    lane-dense ``(4 * rows, 128)`` tile."""
+    by = jnp.concatenate(
+        [((x >> jnp.uint32(8 * b)) & jnp.uint32(0xFF)).astype(jnp.int32)
+         for b in range(4)], axis=0)
+
+    def body(v, acc):
+        return jnp.where(bins == v, acc + _total((by == v).astype(jnp.int32)),
+                         acc)
+
+    return lax.fori_loop(jnp.int32(0), jnp.int32(256), body, blk)
+
+
 def _kernel(x_ref, xp_ref, out_ref):
     i = pl.program_id(1)
     x = x_ref[0]                      # (ROWS, 128) uint32
     flips = x ^ xp_ref[0]
 
-    shifts = lax.broadcasted_iota(jnp.uint32, (ROWS, 128, 32), 2)
-    one = jnp.uint32(1)
-
-    def count(w):
-        return ((w[:, :, None] >> shifts) & one).sum((0, 1), dtype=jnp.int32)
-
-    ones = count(x)
-    trans = count(flips)
-
-    vals = lax.broadcasted_iota(jnp.int32, (ROWS, 128, 256), 2)
-    hist = jnp.zeros((256,), jnp.int32)
-    for b in range(4):
-        by = ((x >> jnp.uint32(8 * b)) & jnp.uint32(0xFF)).astype(jnp.int32)
-        hist = hist + (by[:, :, None] == vals).sum((0, 1), dtype=jnp.int32)
-
+    row = lax.broadcasted_iota(jnp.int32, (OUT_ROWS, 128), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (OUT_ROWS, 128), 1)
     blk = jnp.zeros((OUT_ROWS, 128), jnp.int32)
-    blk = blk.at[0, :32].set(ones)
-    blk = blk.at[1, :32].set(trans)
-    blk = blk.at[2, :].set(hist[:128])
-    blk = blk.at[3, :].set(hist[128:])
+    for p in range(32):
+        bit = jnp.uint32(p)
+        ones = _total(((x >> bit) & jnp.uint32(1)).astype(jnp.int32))
+        trans = _total(((flips >> bit) & jnp.uint32(1)).astype(jnp.int32))
+        blk = jnp.where((row == 0) & (lane == p), ones, blk)
+        blk = jnp.where((row == 1) & (lane == p), trans, blk)
+    # rows 2-3 hold bins 0..255; rows 0-1 map to negative bins (untouched)
+    blk = byte_hist_block(x, blk, (row - 2) * 128 + lane)
 
     @pl.when(i == 0)
     def _init():
@@ -83,10 +100,10 @@ def scoregrid_blocks(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, ROWS, 128), lambda c, i: (c, i, 0)),
-            pl.BlockSpec((1, ROWS, 128), lambda c, i: (c, i, 0)),
+            pl.BlockSpec((1, ROWS, 128), lambda c, i: (c, i, jnp.int32(0))),
+            pl.BlockSpec((1, ROWS, 128), lambda c, i: (c, i, jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((1, OUT_ROWS, 128), lambda c, i: (c, 0, 0)),
+        out_specs=pl.BlockSpec((1, OUT_ROWS, 128), lambda c, i: (c, jnp.int32(0), jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((rows, OUT_ROWS, 128), jnp.int32),
         interpret=interpret,
     )(x, xprev)

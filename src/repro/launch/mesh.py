@@ -11,17 +11,26 @@ carries either data parallelism (default) or pipeline stages
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple, axes: tuple):
+    # Auto axes: the models place arrays through sharding constraints
+    # (distributed/sharding.py); explicit axes, make_mesh's default since
+    # jax 0.7, would make every gather of a sharded table name its output
+    # sharding
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (host) devices exist — tests/examples."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

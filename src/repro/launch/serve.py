@@ -134,6 +134,9 @@ def main(argv=None):
                     help="fraction of requests that read a sub-range")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     if args.tensors:
         return serve_tensors(args)
     if not args.arch:

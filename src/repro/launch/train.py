@@ -27,6 +27,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import CLI_IDS, get_config
 from repro.data.tokens import stream_for
 from repro.distributed.steps import make_train_step, shardings_for_train
+from repro.launch.compile_cache import use_checkout_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--model-par", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_checkout_cache()
 
     cfg = get_config(CLI_IDS.get(args.arch, args.arch), reduced=args.reduced)
     model = build_model(cfg)

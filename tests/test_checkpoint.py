@@ -10,7 +10,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager, restore_tree, save_tree
 from repro.data.shard_store import ShardStore
-from repro.data.tokens import TokenStream
+from repro.data.tokens import MultimodalStream, TokenStream
 
 
 def mk_tree(seed=0):
@@ -170,6 +170,19 @@ def test_data_pipeline_o1_resume():
     s, b = next(it)
     assert s == 5
     assert np.array_equal(np.asarray(b5["tokens"]), np.asarray(b["tokens"]))
+
+
+@pytest.mark.parametrize("kind", ["frames", "patches"])
+def test_multimodal_stream_batches_resume(kind):
+    ms = MultimodalStream(vocab=1000, batch=2, seq=16, d_model=8, kind=kind,
+                          prefix=4, seed=3)
+    it = ms.batches(start_step=7)
+    for want in (7, 8):
+        step, got = next(it)
+        ref = ms.batch_at(want)
+        assert step == want and got.keys() == ref.keys()
+        for k in ref:
+            assert np.array_equal(np.asarray(got[k]), np.asarray(ref[k])), k
 
 
 def test_shard_store_roundtrip_and_random_access(tmp_path):

@@ -87,6 +87,22 @@ def _encode_forced(x, method: str):
         return pipeline.apply_transform(x, "identity")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _compiled_shapes():
+    """Compile the codec once for every chunk shape the strategies can
+    draw, so hypothesis's per-example deadline times the round trip and
+    not a first-call XLA compile."""
+    for dtype in FLOAT_DTYPES:
+        for method in METHODS:
+            for nchunks in range(1, 5):
+                for per_chunk in SIZES[1:]:
+                    for specials in (False, True):
+                        x = _data(dtype, per_chunk * nchunks, 0, specials)
+                        for c in range(nchunks):
+                            chunk = x[c * per_chunk:(c + 1) * per_chunk]
+                            pipeline.decode(_encode_forced(chunk, method))
+
+
 # ---------------------------------------------------------------------------
 # dumps / loads: single-record containers
 # ---------------------------------------------------------------------------

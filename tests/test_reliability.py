@@ -442,6 +442,10 @@ class TestWatchdog:
         x = _data(n=20000, seed=12)
         p = tmp_path / "h.fpc"
         _write_container(p, x, chunk=2500)
+        # a first decode compiles its device programs; the watched read
+        # below is the steady state
+        with ContainerReader(p) as r:
+            r.read_all()
         with caplog.at_level(logging.WARNING, "repro.reliability"):
             with ContainerReader(p) as r:
                 got = r.read_all(parallel=True)

@@ -119,12 +119,12 @@ def test_family_diverse_finalists():
     x = gas_turbine_emissions(5000)
     xf = x.reshape(-1)
     finite = np.isfinite(xf) & (xf != 0)
-    from repro.core.float_bits import normalize_to_binade, spec_for
-    from repro.core.lossless import significand_int
+    from repro.core.float_bits import normalize_bits, spec_for
+    from repro.core.lossless import significand_from_bits
 
-    spec = spec_for(jnp.asarray(x))
-    y01, e, s = normalize_to_binade(jnp.asarray(xf[finite]), spec)
-    X = significand_int(y01, 0, spec)
+    spec = spec_for(x)
+    y01, e, s = normalize_bits(xf[finite].view(np.uint64), spec)
+    X = significand_from_bits(y01, spec)
     zfn = lambda b: len(zlib.compress(b, 6))
     ranked = pipeline._select_analytic(
         xf, finite, X, spec, pipeline.DEFAULT_CANDIDATES, zfn, 100.0,

@@ -215,6 +215,36 @@ def test_stream_chunks_propagates_write_failure():
                             queue_depth=2)
 
 
+@pytest.mark.parametrize("writer", ["container", "dataset"])
+def test_chunk_encode_error_reaches_writer_caller(writer, tmp_path,
+                                                  monkeypatch):
+    """Only a TransformError (the data rejected the transform) falls back
+    to identity.  Any other error raised inside a chunk encode, such as a
+    device program the compiler refused, reaches the caller instead of
+    turning into a silent identity write."""
+    real = S.pipeline.apply_transform
+
+    class DeviceFault(RuntimeError):
+        pass
+
+    def failing(chunk, method, *a, **kw):
+        if method != "identity":
+            raise DeviceFault("compile refused")
+        return real(chunk, method, *a, **kw)
+
+    monkeypatch.setattr(S.pipeline, "apply_transform", failing)
+    x = 1.0 + np.arange(4096) / 256.0
+    with pytest.raises(DeviceFault):
+        if writer == "container":
+            with ContainerWriter(tmp_path / "f.fpc", dtype=np.float64,
+                                 method="compact_bins",
+                                 params={"n_bins": 4}) as w:
+                w.append(x)
+        else:
+            DatasetWriter(tmp_path / "ds", dtype=np.float64, chunk=1024,
+                          method="compact_bins").write([x])
+
+
 def test_shard_write_empty_keeps_single_chunk():
     """Empty shards still carry one empty chunk (pre-streaming layout)."""
     import tempfile
