@@ -24,6 +24,7 @@ import zlib
 
 import numpy as np
 
+from .. import obs
 from ..core import transforms as T
 from ..core.pipeline import Encoded
 from .backends import Backend, ContainerError, get_backend
@@ -372,6 +373,7 @@ def _decompress_exact(be: Backend, buf: bytes, expected: int) -> bytes:
     return out
 
 
+@obs.span("container.serialize")
 def serialize_chunk(enc: Encoded, backend: str | Backend = "zlib") -> bytes:
     """One :class:`Encoded` -> a self-delimiting checksummed record."""
     be = _resolve_backend(backend)

@@ -35,6 +35,7 @@ from pathlib import Path
 
 import numpy as np
 
+from .. import obs
 from ..core import pipeline, streaming as _streaming
 from ..core.float_bits import BF16, F16, F32, F64
 from ..reliability import durable as _durable, faults as _faults, watchdog as _watchdog
@@ -574,6 +575,7 @@ class ContainerReader:
         comp = sum(e["length"] for e in self._entries)
         return comp / max(raw, 1)
 
+    @obs.span("container.read_record")
     def _record(self, i: int) -> bytes:
         e = self._entries[i]
         with self._io_lock:

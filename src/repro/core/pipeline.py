@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import plans
 from . import scoring as S
 from . import transforms as T
@@ -163,6 +164,7 @@ def _meta_bytes(meta) -> int:
     return -(-meta.nbits() // 8) if meta is not None else 16
 
 
+@obs.span("encode.classic")
 def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
     """Run candidate `name` forward on the full significand array, verify the
     inverse chunk-by-chunk, and fetch (values, offsets, verdict) in a single
@@ -177,7 +179,8 @@ def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
         Xr = inv(Xt[s:e], off[s:e], _slice_meta(meta, s, e), spec=spec)
         ok = ok & jnp.all(Xr == X[s:e])
     vals = significand_to_bits(Xt, off.astype(jnp.int32), spec)
-    vals_np, ok_np = jax.device_get((vals, ok))
+    with obs.span("encode.device_wait"):
+        vals_np, ok_np = jax.device_get((vals, ok))
     if not bool(ok_np):
         return None
     return vals_np.view(spec.float_dtype), meta
@@ -220,10 +223,11 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
 
     def entropy(byte_stream):
         b = byte_stream.astype(jnp.int32)
-        hist = jnp.bincount(b, length=256)
-        freq = K.quantize_freqs_dev(hist).astype(jnp.int32)
-        cum = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                               jnp.cumsum(freq)[:-1]])
+        with jax.named_scope("histogram"):
+            hist = jnp.bincount(b, length=256)
+            freq = K.quantize_freqs_dev(hist).astype(jnp.int32)
+            cum = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                   jnp.cumsum(freq)[:-1]])
         sym = jnp.pad(b, (0, steps * lanes - n_bytes)).reshape(steps, lanes)
 
         def step(x, xs):
@@ -231,14 +235,16 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             return K.encode_scan_body(x, t, s, jnp.int32(n_bytes), freq,
                                       cum, lanes)
 
-        x, (b0, b1, e0, e1) = jax.lax.scan(
-            step, jnp.full((lanes,), K.RANS_L, jnp.int32),
-            (jnp.arange(steps, dtype=jnp.int32), sym), reverse=True,
-        )
+        with jax.named_scope("lane_scan"):
+            x, (b0, b1, e0, e1) = jax.lax.scan(
+                step, jnp.full((lanes,), K.RANS_L, jnp.int32),
+                (jnp.arange(steps, dtype=jnp.int32), sym), reverse=True,
+            )
         return freq, b0, b1, e0, e1, x
 
     def val_bytes(vals):
-        return jax.lax.bitcast_convert_type(vals, jnp.uint8).reshape(-1)
+        with jax.named_scope("pack"):
+            return jax.lax.bitcast_convert_type(vals, jnp.uint8).reshape(-1)
 
     if method == "identity":
         @jax.jit
@@ -254,15 +260,20 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
         def run_sse(X):
             lo = jnp.int64(1) << l
             top = jnp.int64(1) << (l + 1)
-            x_min = jnp.min(X)
-            ok = (x_min >= lo) & (jnp.max(X) < (lo << 1))
-            Y, j, parity, j_max = T._sse_core(X, x_min, jnp.int64(w_eff), top)
-            # in-graph inverse verification (same arithmetic as
-            # shift_save_even_inverse, replayed from the traced meta)
-            a_base = top - x_min - j * jnp.int64(w_eff)
-            A = a_base + (a_base & 1) + parity.astype(jnp.int64)
-            ok &= jnp.all((Y << 1) - A == X)
-            vals = significand_to_bits(Y, jnp.ones(Y.shape, jnp.int32), spec)
+            with jax.named_scope("apply"):
+                x_min = jnp.min(X)
+                ok = (x_min >= lo) & (jnp.max(X) < (lo << 1))
+                Y, j, parity, j_max = T._sse_core(X, x_min,
+                                                  jnp.int64(w_eff), top)
+            with jax.named_scope("verify"):
+                # in-graph inverse verification (same arithmetic as
+                # shift_save_even_inverse, replayed from the traced meta)
+                a_base = top - x_min - j * jnp.int64(w_eff)
+                A = a_base + (a_base & 1) + parity.astype(jnp.int64)
+                ok &= jnp.all((Y << 1) - A == X)
+            with jax.named_scope("pack"):
+                vals = significand_to_bits(Y, jnp.ones(Y.shape, jnp.int32),
+                                           spec)
             return (ok, vals) + entropy(val_bytes(vals)) + (x_min, j, parity,
                                                             j_max)
 
@@ -276,14 +287,17 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
         @jax.jit
         def run_cb(X):
             lo = jnp.int64(1) << l
-            ok = (jnp.min(X) >= lo) & (jnp.max(X) < (lo << 1))
-            Xt, shifts, new_lo, fits = T._cb_core(X, k=k, l=l)
-            thr = new_lo[1:]
-            bin_id = (jnp.searchsorted(thr, Xt, side="right") if k > 1
-                      else jnp.zeros(Xt.shape, jnp.int64))
-            ok &= fits & jnp.all(Xt - shifts[bin_id] == X)
-            vals = significand_to_bits(Xt, jnp.zeros(Xt.shape, jnp.int32),
-                                       spec)
+            with jax.named_scope("apply"):
+                ok = (jnp.min(X) >= lo) & (jnp.max(X) < (lo << 1))
+                Xt, shifts, new_lo, fits = T._cb_core(X, k=k, l=l)
+                thr = new_lo[1:]
+            with jax.named_scope("verify"):
+                bin_id = (jnp.searchsorted(thr, Xt, side="right") if k > 1
+                          else jnp.zeros(Xt.shape, jnp.int64))
+                ok &= fits & jnp.all(Xt - shifts[bin_id] == X)
+            with jax.named_scope("pack"):
+                vals = significand_to_bits(
+                    Xt, jnp.zeros(Xt.shape, jnp.int32), spec)
             return (ok, vals) + entropy(val_bytes(vals)) + (shifts, thr)
 
         return run_cb
@@ -291,6 +305,7 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
     return None
 
 
+@obs.span("encode.frame")
 def _fused_frame(lanes: int, n_bytes: int, freq, b0, b1, e0, e1, x) -> bytes:
     from ..kernels.rans import ref as R
 
@@ -307,6 +322,7 @@ def _fused_geometry(n_bytes: int):
     return lanes, bucket_steps(-(-n_bytes // lanes))
 
 
+@obs.span("encode.fused")
 def _fused_identity(xf: np.ndarray, shape, spec_name: str) -> Encoded | None:
     """Identity chunk with the data stream rANS-coded on device (stats pass
     + lane scan in one dispatch); None when too small to pay for a scan."""
@@ -316,7 +332,9 @@ def _fused_identity(xf: np.ndarray, shape, spec_name: str) -> Encoded | None:
     lanes, steps = _fused_geometry(n_bytes)
     prog = _fused_program("identity", (), spec_name, 0, n_bytes, steps, lanes)
     S.PHASE2.dispatches += 1
-    out = jax.device_get(prog(np.ascontiguousarray(xf).view(np.uint8)))
+    out = prog(np.ascontiguousarray(xf).view(np.uint8))
+    with obs.span("encode.device_wait"):
+        out = jax.device_get(out)
     S.PHASE2.device_gets += 1
     _ok, freq, b0, b1, e0, e1, x = out
     return Encoded(
@@ -328,6 +346,7 @@ def _fused_identity(xf: np.ndarray, shape, spec_name: str) -> Encoded | None:
     )
 
 
+@obs.span("encode.fused")
 def _fused_encode(prep: "_Prepared", name: str, p: dict) -> Encoded | None:
     """Encode one chunk through the fused device program; returns the
     Encoded carrying the framed rANS payload, or None when this
@@ -353,7 +372,9 @@ def _fused_encode(prep: "_Prepared", name: str, p: dict) -> Encoded | None:
     if prog is None:
         return None
     S.PHASE2.dispatches += 1
-    out = jax.device_get(prog(prep.X))
+    out = prog(prep.X)
+    with obs.span("encode.device_wait"):
+        out = jax.device_get(out)
     S.PHASE2.device_gets += 1
     if not bool(out[0]):
         return None          # rejected in-graph: never shipped
@@ -469,6 +490,7 @@ class _Prepared:
         )
 
 
+@obs.span("encode.prepare")
 def _prepare(x, spec: FloatSpec | None = None) -> _Prepared:
     xf = np.asarray(x).reshape(-1)
     spec = spec or spec_for(xf)
@@ -479,8 +501,9 @@ def _prepare(x, spec: FloatSpec | None = None) -> _Prepared:
     if active.shape[0]:
         y01, exps, signs = normalize_bits(jnp.asarray(active), spec)
         X = significand_from_bits(y01, spec)
-        exps_np = np.asarray(exps, np.int64)
-        signs_np = np.asarray(signs, np.uint8)
+        with obs.span("encode.device_wait"):
+            exps_np = np.asarray(exps, np.int64)
+            signs_np = np.asarray(signs, np.uint8)
     else:
         X = None
         exps_np = np.zeros(0, np.int64)
@@ -813,9 +836,10 @@ def _encode_full(
         if cached is not None:
             ranked = list(cached)
     if ranked is None:
-        ranked, first_applied = _rank_candidates(
-            prep, candidates, size_fn, sample_elems, top_k, engine, backend
-        )
+        with obs.span("select.probe"):
+            ranked, first_applied = _rank_candidates(
+                prep, candidates, size_fn, sample_elems, top_k, engine,
+                backend)
         if key is not None:
             _PLAN_CACHE.put(key, list(ranked))
 
@@ -922,7 +946,9 @@ def _select_analytic(
 
     # sample extrema computed ONCE and shared by the whole candidate grid;
     # the single domain check below covers every fused scorer dispatch
-    mn, mx = jax.device_get((jnp.min(Xs), jnp.max(Xs)))
+    mn, mx = jnp.min(Xs), jnp.max(Xs)
+    with obs.span("select.device_wait"):
+        mn, mx = jax.device_get((mn, mx))
     extrema = (int(mn), int(mx))
     T._check_domain(Xs, spec, extrema)
 
@@ -1064,7 +1090,8 @@ def _select_analytic(
             except T.TransformError:
                 continue
             vals = significand_to_bits(Xt, off.astype(jnp.int32), spec)
-            data_bytes = np.asarray(vals).tobytes()
+            with obs.span("select.device_wait"):
+                data_bytes = np.asarray(vals).tobytes()
             meta_cost = _scaled_meta_bytes(meta, scale)
         exact.append(
             (size_fn(data_bytes) * scale + pass_cost + meta_cost
@@ -1122,6 +1149,7 @@ def _select_exact(xf, finite, X, spec, candidates, size_fn, common_meta):
     return ranked, first_applied
 
 
+@obs.span("decode.inverse")
 def decode(enc: Encoded) -> np.ndarray:
     spec = SPECS[enc.spec_name]
     n = enc.n
@@ -1143,5 +1171,7 @@ def decode(enc: Encoded) -> np.ndarray:
     X = inv(significand_from_bits(b, spec), off, enc.meta, spec=spec)
     y01 = significand_to_bits(X, jnp.zeros_like(off), spec)
     vals = denormalize_bits(y01, exps, signs, spec)
-    out[~pass_mask] = np.asarray(vals).view(spec.float_dtype)
+    with obs.span("decode.device_wait"):
+        vals = np.asarray(vals)
+    out[~pass_mask] = vals.view(spec.float_dtype)
     return out.reshape(np.shape(enc.data))

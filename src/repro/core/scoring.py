@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import obs
 from ..kernels import INTERPRET_DEFAULT
 from ..kernels.scoregrid.ops import (
     byte_entropy_bits,
@@ -208,7 +209,8 @@ def fetch_scores(scores: list[CandidateScore]) -> None:
     pending = [s for s in scores if s._dev is not None]
     if not pending:
         return
-    vals = jax.device_get([s._dev for s in pending])
+    with obs.span("select.device_wait"):
+        vals = jax.device_get([s._dev for s in pending])
     PHASE1.device_gets += 1
     for s, v in zip(pending, vals):
         v = np.atleast_1d(np.asarray(v, np.float64))
@@ -486,41 +488,42 @@ def _grid_score(Xs, x_min, dyn, spec: FloatSpec, plan: tuple):
     rANS statistics pass, retained for the same reason), and each
     candidate's per-sample metadata arrays (sse chunk-ids/evenness, cb
     shifts/floors) for the metadata probe."""
-    words, fixed, psamp, valid, extras = [], [], [], [], []
-    for entry, d in zip(plan, dyn):
-        fam = entry[0]
-        if fam == "sse":
-            built = _sse_build(Xs, x_min, entry[1], entry[2], spec)
-        elif fam == "ms":
-            a1, a_const, thresh = d
-            built = _ms_build(Xs, a1, a_const, thresh, entry[1], spec)
-        elif fam == "ss":
-            a_align, Ae, Ao, enabled, cap = d
-            built = _ss_build(Xs, a_align, Ae, Ao, enabled, cap, spec)
-        else:
-            built = _cb_build(Xs, entry[1], spec)
-        w, f, s_, v, ex = built
-        words.append(w)
-        fixed.append(jnp.asarray(f, jnp.float64))
-        psamp.append(jnp.asarray(s_, jnp.float64))
-        valid.append(jnp.asarray(v).astype(jnp.float64))
-        extras.append(ex)
-    W = jnp.stack(words)
-    n = W.shape[1]
-    lanes = spec.width // 8
-    ones, trans, hist = plane_byte_stats_grid(
-        W, lanes=lanes, use_pallas=_USE_PALLAS_GRID,
-        interpret=INTERPRET_DEFAULT,
-    )
-    mat = jnp.stack([
-        finalize_bits_grid(ones, trans, hist, n, lanes),
-        jnp.stack(fixed),
-        jnp.stack(psamp),
-        jnp.stack(valid),
-        byte_entropy_bits(hist, n, lanes),
-        (hist > 0).sum(axis=-1).astype(jnp.float64),
-    ], axis=1)
-    return mat, W, hist, tuple(extras)
+    with jax.named_scope("grid_score"):
+        words, fixed, psamp, valid, extras = [], [], [], [], []
+        for entry, d in zip(plan, dyn):
+            fam = entry[0]
+            if fam == "sse":
+                built = _sse_build(Xs, x_min, entry[1], entry[2], spec)
+            elif fam == "ms":
+                a1, a_const, thresh = d
+                built = _ms_build(Xs, a1, a_const, thresh, entry[1], spec)
+            elif fam == "ss":
+                a_align, Ae, Ao, enabled, cap = d
+                built = _ss_build(Xs, a_align, Ae, Ao, enabled, cap, spec)
+            else:
+                built = _cb_build(Xs, entry[1], spec)
+            w, f, s_, v, ex = built
+            words.append(w)
+            fixed.append(jnp.asarray(f, jnp.float64))
+            psamp.append(jnp.asarray(s_, jnp.float64))
+            valid.append(jnp.asarray(v).astype(jnp.float64))
+            extras.append(ex)
+        W = jnp.stack(words)
+        n = W.shape[1]
+        lanes = spec.width // 8
+        ones, trans, hist = plane_byte_stats_grid(
+            W, lanes=lanes, use_pallas=_USE_PALLAS_GRID,
+            interpret=INTERPRET_DEFAULT,
+        )
+        mat = jnp.stack([
+            finalize_bits_grid(ones, trans, hist, n, lanes),
+            jnp.stack(fixed),
+            jnp.stack(psamp),
+            jnp.stack(valid),
+            byte_entropy_bits(hist, n, lanes),
+            (hist > 0).sum(axis=-1).astype(jnp.float64),
+        ], axis=1)
+        return mat, W, hist, tuple(extras)
 
 
 def score_candidates_stacked(candidates, Xs, spec: FloatSpec, extrema,
@@ -576,9 +579,10 @@ def score_candidates_stacked(candidates, Xs, spec: FloatSpec, extrema,
         # ONE device_get resolves the score lanes, the retained word grid +
         # byte histograms + metadata extras (finalist reuse), and every
         # generic handle
-        mat, W_np, hist_np, extras_np, vals = jax.device_get(
-            (out, W, hist, extras, handles)
-        )
+        with obs.span("select.device_wait"):
+            mat, W_np, hist_np, extras_np, vals = jax.device_get(
+                (out, W, hist, extras, handles)
+            )
         PHASE1.device_gets += 1
     else:
         mat, W_np, hist_np, extras_np, vals = out, None, None, (), []

@@ -41,6 +41,7 @@ import threading
 
 import numpy as np
 
+from .. import obs
 from . import pipeline, plans, transforms as T
 
 DEFAULT_WINDOW_BYTES = 4 << 20
@@ -165,6 +166,7 @@ class WindowPlanner:
         self.stats = {"probes": 0, "windows": 0, "reused_windows": 0,
                       "drift_refreshes": 0}
 
+    @obs.span("select.probe")
     def _select(self, chunk, stat: str, sample=None,
                 fp: plans.StreamFingerprint | None = None) -> None:
         if sample is None:
@@ -269,7 +271,8 @@ def stream_chunks(writer, chunks, queue_depth: int | None = None) -> int:
             if failure:
                 continue  # discard: keep unblocking the producer
             try:
-                writer._write_record(*item)
+                with obs.span("stream.write_record"):
+                    writer._write_record(*item)
             except BaseException as e:  # noqa: BLE001 - re-raised in caller
                 failure.append(e)
 
@@ -278,14 +281,17 @@ def stream_chunks(writer, chunks, queue_depth: int | None = None) -> int:
     n = 0
     try:
         for chunk in chunks:
-            rec = writer.encode_record(chunk)
+            with obs.span("stream.encode_record"):
+                rec = writer.encode_record(chunk)
             if failure:
                 break
-            q.put(rec)
+            with obs.span("stream.queue_wait"):
+                q.put(rec)
             n += 1
     finally:
-        q.put(_DONE)
-        t.join()
+        with obs.span("stream.queue_wait"):
+            q.put(_DONE)
+            t.join()
     if failure:
         raise failure[0]
     return n

@@ -42,6 +42,7 @@ from pathlib import Path
 
 import numpy as np
 
+from .. import obs
 from ..container import ContainerReader, ContainerWriter
 from ..container.format import dtype_name, resolve_dtype
 from ..core import streaming as _streaming
@@ -119,7 +120,6 @@ class DatasetWriter:
                     f"part_elems ({part_elems}) must be a positive multiple "
                     f"of chunk ({chunk})"
                 )
-            self.root.mkdir(parents=True, exist_ok=True)
             self._manifest = {
                 "format": DATASET_FORMAT,
                 "dtype": dtype_name(dtype),
@@ -131,12 +131,15 @@ class DatasetWriter:
                 "total": 0,
                 "complete": False,
             }
-            # the initial manifest is durable before any data: a resuming
-            # writer always finds the recorded geometry
-            self._write_manifest()
+            with obs.span("dataset.create"):
+                self.root.mkdir(parents=True, exist_ok=True)
+                # the initial manifest is durable before any data: a
+                # resuming writer always finds the recorded geometry
+                self._write_manifest()
 
     # -- manifest plumbing --------------------------------------------------
 
+    @obs.span("dataset.manifest")
     def _write_manifest(self) -> None:
         _durable.write_bytes(
             self.root / MANIFEST_NAME,
@@ -214,7 +217,7 @@ class DatasetWriter:
                     yield c
 
             # phase 1: the part container itself (stage -> fsync -> rename)
-            with ContainerWriter(
+            with obs.span("dataset.part", part=idx), ContainerWriter(
                 self.root / name, dtype=dt, backend=m["backend"],
                 method=self._method, plan=self._plan,
                 user_meta={"dtype": m["dtype"], "chunk": chunk, "part": idx},

@@ -202,11 +202,12 @@ def encode_scan(sym, n, freq, cum, steps: int, lanes: int):
         t, s = xs
         return encode_scan_body(x, t, s, n, freq, cum, lanes)
 
-    x, (b0, b1, e0, e1) = lax.scan(
-        step, jnp.full((lanes,), RANS_L, jnp.int32),
-        (jnp.arange(steps, dtype=jnp.int32), sym),
-        reverse=True,
-    )
+    with jax.named_scope("encode_scan"):
+        x, (b0, b1, e0, e1) = lax.scan(
+            step, jnp.full((lanes,), RANS_L, jnp.int32),
+            (jnp.arange(steps, dtype=jnp.int32), sym),
+            reverse=True,
+        )
     return b0, b1, e0, e1, x
 
 
@@ -267,8 +268,9 @@ def decode_scan(states, bodies, body_lens, n, slot2sym, freq, cum,
             ptr = ptr + m.astype(jnp.int32)
         return (x, ptr), jnp.where(act, s, 0)
 
-    (x, ptr), syms = lax.scan(
-        step, (x0, jnp.zeros(lanes, jnp.int32)),
-        jnp.arange(steps, dtype=jnp.int32),
-    )
+    with jax.named_scope("decode_scan"):
+        (x, ptr), syms = lax.scan(
+            step, (x0, jnp.zeros(lanes, jnp.int32)),
+            jnp.arange(steps, dtype=jnp.int32),
+        )
     return syms, x, ptr

@@ -25,6 +25,7 @@ import os
 
 import numpy as np
 
+from ... import obs
 from .. import INTERPRET_DEFAULT
 from . import ref
 from .ref import RansError  # noqa: F401  (re-exported for callers)
@@ -52,6 +53,7 @@ def _use_scan(steps: int) -> bool:
     return not in_decode_pool()
 
 
+@obs.span("rans.encode")
 def compress(data: bytes, lanes: int | None = None,
              counts=None) -> bytes:
     """bytes -> framed rANS stream.
@@ -69,8 +71,9 @@ def compress(data: bytes, lanes: int | None = None,
     if counts is None and _ON_TPU and n:
         from .kernel import byte_hist
 
-        counts = np.asarray(byte_hist(arr, use_pallas=True,
-                                      interpret=INTERPRET_DEFAULT), np.int64)
+        counts = byte_hist(arr, use_pallas=True, interpret=INTERPRET_DEFAULT)
+        with obs.span("rans.device_wait"):
+            counts = np.asarray(counts, np.int64)
     return ref.encode(arr, lanes=lanes, counts=counts)
 
 
@@ -86,14 +89,17 @@ def _compress_scan(arr: np.ndarray, lanes: int, counts) -> bytes:
     steps = bucket_steps(-(-n // lanes))
     sym = np.zeros(steps * lanes, np.int32)
     sym[:n] = arr
-    b0, b1, e0, e1, x = map(np.asarray, encode_scan(
+    out = encode_scan(
         sym.reshape(steps, lanes), n, freq.astype(np.int32),
         cum.astype(np.int32), steps=steps, lanes=lanes,
-    ))
+    )
+    with obs.span("rans.device_wait"):
+        b0, b1, e0, e1, x = map(np.asarray, out)
     head = ref._HEADER.pack(ref.FRAME_VERSION, lanes, n)
     return ref.assemble_frame(head, freq, x, b0, b1, e0, e1)
 
 
+@obs.span("rans.decode")
 def decompress(buf: bytes) -> bytes:
     """Framed rANS stream -> bytes (compiled lane loop for large frames,
     numpy reference for small frames and decode-pool workers)."""
@@ -124,7 +130,8 @@ def decompress_device(buf: bytes, interpret: bool | None = None) -> bytes:
         np.repeat(np.arange(256, dtype=np.int32), freq), freq, cum,
         steps=steps, lanes=lanes,
     )
-    syms, x, ptr = map(np.asarray, (syms, x, ptr))
+    with obs.span("rans.device_wait"):
+        syms, x, ptr = map(np.asarray, (syms, x, ptr))
     ref.check_final(x, ptr, body_lens)
     return syms.astype(np.uint8).reshape(-1)[:n].tobytes()
 

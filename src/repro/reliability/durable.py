@@ -25,11 +25,13 @@ import itertools
 import os
 from pathlib import Path
 
+from .. import obs
 from . import faults
 
 _seq = itertools.count()
 
 
+@obs.span("durable.fsync_dir")
 def fsync_dir(path: str | Path) -> None:
     """fsync a directory so a rename inside it survives OS crash.  No-op on
     platforms whose directories cannot be opened (e.g. Windows) — there
@@ -69,10 +71,12 @@ class DurableFile:
         self.file.flush()
         faults.maybe_crash("durable.staged")
         if self.fsync:
-            os.fsync(self.file.fileno())
+            with obs.span("durable.fsync"):
+                os.fsync(self.file.fileno())
         self.file.close()
         faults.maybe_crash("durable.synced")
-        os.replace(self.stage, self.path)
+        with obs.span("durable.replace"):
+            os.replace(self.stage, self.path)
         faults.maybe_crash("durable.replaced")
         if self.fsync:
             fsync_dir(self.path.parent)

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import threading
 
+from .. import obs
+
 
 class _Call:
     __slots__ = ("event", "result", "exc")
@@ -63,7 +65,8 @@ class SingleFlight:
                 self.coalesced += 1
                 leader = False
         if not leader:
-            call.event.wait()
+            with obs.span("flight.wait"):
+                call.event.wait()
             if call.exc is not None:
                 raise call.exc
             return call.result, True

@@ -29,6 +29,7 @@ from pathlib import Path
 
 import numpy as np
 
+from .. import obs
 from ..container import ContainerReader
 from ..container.format import resolve_dtype
 from ..data.dataset import DatasetReader
@@ -99,12 +100,14 @@ class TensorServer:
     def _span(self, name: str, lo: int, hi: int) -> np.ndarray:
         """Cached + coalesced decoded span of chunks [lo, hi)."""
         key = (name, lo, hi)
-        arr = self._cache.get(key)
+        with obs.span("cache.get"):
+            arr = self._cache.get(key)
         if arr is not None:
             return arr
 
         def decode():
-            a = self._decode_span(name, lo, hi)
+            with obs.span("server.decode_span", tensor=name, lo=lo, hi=hi):
+                a = self._decode_span(name, lo, hi)
             with self._stats_lock:
                 self._decodes += 1
                 self._decoded_bytes += a.nbytes
@@ -144,13 +147,15 @@ class TensorServer:
         the covering chunks (partial read; read-only).  Byte-identical to
         ``read(name).reshape(-1)[start:stop]`` — the partial-read contract
         (docs/serving.md §Partial reads)."""
-        with self._stats_lock:
-            self._requests["slice"] += 1
-        r = self._reader(name)
-        lo, hi = r.covering_chunks(start, stop)
-        span = self._span(name, lo, hi)
-        off = r.chunk_offsets()[lo]
-        return span[start - off : stop - off]
+        with obs.span("server.read_slice", tensor=name, start=start,
+                      stop=stop):
+            with self._stats_lock:
+                self._requests["slice"] += 1
+            r = self._reader(name)
+            lo, hi = r.covering_chunks(start, stop)
+            span = self._span(name, lo, hi)
+            off = r.chunk_offsets()[lo]
+            return span[start - off : stop - off]
 
     def invalidate(self, name: str) -> None:
         """Forget one tensor (rewritten shard): drop its reader and every
