@@ -1149,29 +1149,114 @@ def _select_exact(xf, finite, X, spec, candidates, size_fn, common_meta):
     return ranked, first_applied
 
 
+# ---------------------------------------------------------------------------
+# decode: the device half of the inverse as ONE compiled program per chunk
+# (binade offsets, the family's inverse, significand -> bit words,
+# denormalization), run with one dispatch and fetched with one wait
+# ---------------------------------------------------------------------------
+
+def _lanes(a, n: int, n_pad: int, dtype=None) -> np.ndarray:
+    """The ``n`` values of a per-sample stream in the first lanes of a
+    zeroed ``n_pad``-long array (the decode program drops the rest)."""
+    a = np.asarray(a, dtype)
+    if a.shape != (n,):
+        raise ValueError(f"per-sample stream holds {a.shape[0]} values, "
+                         f"expected {n}")
+    out = np.zeros(n_pad, a.dtype)
+    out[:n] = a
+    return out
+
+
+def _inverse_args(method: str, meta, spec: FloatSpec, n: int,
+                  n_pad: int) -> tuple:
+    """The family's metadata as the decode program's arguments: per-sample
+    arrays padded to the program's length, scalars and schedules replayed
+    on the host."""
+    if method == "shift_save_even":
+        return (_lanes(meta.chunk_ids, n, n_pad, np.int64),
+                _lanes(meta.evenness, n, n_pad, np.uint8),
+                np.int64(meta.x_min), np.int64(meta.D))
+    if method == "compact_bins":
+        return (np.asarray(meta.shifts, np.int64),
+                np.asarray(meta.thresholds, np.int64))
+    if method == "multiply_shift":
+        return T._ms_inverse_schedule(meta, spec)
+    if method == "shift_separate":
+        from ..kernels.rans.kernel import bucket_steps
+
+        a_align, *sched = T._ss_inverse_schedule(meta, spec)
+        # no-op steps (iteration -1 selects no lane) pad the schedule to a
+        # bucket, so chunks whose iteration counts differ share a program
+        k = bucket_steps(meta.n_iter, floor=8) - meta.n_iter
+        return (a_align,) + tuple(
+            np.pad(a, (0, k), constant_values=fill)
+            for a, fill in zip(sched, (0, 0, 0, -1)))
+    if method != "identity":
+        raise ValueError(f"no inverse transform named {method!r}")
+    return ()
+
+
+@functools.lru_cache(maxsize=64)
+def _inverse_program(method: str, spec_name: str, n_pad: int,
+                     meta_shapes: tuple):
+    """Build (and cache per family, format, bucketed length and the shapes
+    of :func:`_inverse_args`) the decode program: ``(bit words, int32
+    exponents, uint8 signs, *meta) -> output bit words``.  Every traced
+    input and output is an integer array: the float views are the host's."""
+    S.DECODE.add(programs=1)
+    spec = SPECS[spec_name]
+    l = spec.man_bits
+
+    def inverse(X, off, args):
+        if method == "shift_save_even":
+            return T._sse_inverse(X, *args, l)
+        if method == "compact_bins":
+            return T._cb_inverse(X, *args)
+        if method == "multiply_shift":
+            return T._ms_inv_loop(X, off, *args)
+        if method == "shift_separate":
+            return T._ss_inv_loop(X, off, *args)
+        return X
+
+    @jax.jit
+    def run_inverse(b, exps, signs, *args):
+        with jax.named_scope("inverse"):
+            # the transform landed each value at binade `off`
+            off = ((b >> l) & spec.uint_dtype(spec.exp_mask)).astype(
+                jnp.int32) - spec.bias
+            X = inverse(significand_from_bits(b, spec), off, args)
+        with jax.named_scope("denormalize"):
+            y01 = significand_to_bits(X, jnp.zeros_like(off), spec)
+            return denormalize_bits(y01, exps, signs, spec)
+
+    return run_inverse
+
+
 @obs.span("decode.inverse")
 def decode(enc: Encoded) -> np.ndarray:
     spec = SPECS[enc.spec_name]
-    n = enc.n
     flat = np.asarray(enc.data).reshape(-1)
     out = flat.copy()
-    if not enc.n_active:  # identity / all-passthrough: stored verbatim
+    n = enc.n_active
+    if not n:  # identity / all-passthrough: stored verbatim
         return out.reshape(np.shape(enc.data))
     from ..compression.bitplane import decompress_int_stream
+    from ..kernels.rans.kernel import bucket_steps
 
-    pass_mask = _unpack_z(enc.passthrough_z, n).astype(bool)
-    exps = decompress_int_stream(enc.exponents_z, enc.n_active).astype(np.int32)
-    signs = _unpack_z(enc.signs_z, enc.n_active)
+    pass_mask = _unpack_z(enc.passthrough_z, enc.n).astype(bool)
+    # lengths share programs on the rANS scans' quarter-power-of-two ladder
+    n_pad = bucket_steps(n)
     # bit words in, bit words out: the float views are taken on the host
-    b = jnp.asarray(flat[~pass_mask].view(spec.uint_dtype))
-    # the transform landed each value at binade `off`
-    off = ((b >> spec.man_bits) & spec.uint_dtype(spec.exp_mask)).astype(
-        jnp.int32) - spec.bias
-    _, inv = T.TRANSFORMS[enc.method]
-    X = inv(significand_from_bits(b, spec), off, enc.meta, spec=spec)
-    y01 = significand_to_bits(X, jnp.zeros_like(off), spec)
-    vals = denormalize_bits(y01, exps, signs, spec)
+    b = _lanes(flat[~pass_mask].view(spec.uint_dtype), n, n_pad)
+    exps = _lanes(decompress_int_stream(enc.exponents_z, n), n, n_pad,
+                  np.int32)
+    signs = _lanes(_unpack_z(enc.signs_z, n), n, n_pad, np.uint8)
+    args = _inverse_args(enc.method, enc.meta, spec, n, n_pad)
+    prog = _inverse_program(enc.method, spec.name, n_pad,
+                            tuple((a.shape, a.dtype.str) for a in args))
+    S.DECODE.add(dispatches=1)
+    vals = prog(b, exps, signs, *args)
     with obs.span("decode.device_wait"):
         vals = np.asarray(vals)
-    out[~pass_mask] = vals.view(spec.float_dtype)
+    out[~pass_mask] = vals[:n].view(spec.float_dtype)
     return out.reshape(np.shape(enc.data))

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +115,34 @@ class Phase2Stats:
 
 
 PHASE2 = Phase2Stats()
+
+
+@dataclasses.dataclass
+class DecodeStats:
+    """Observable decode cost model, same contract as :class:`Phase2Stats`.
+    A non-identity chunk's inverse is one run of a compiled program
+    (``pipeline._inverse_program``) fetched with one wait; identity and
+    all-passthrough chunks run nothing.  Asserted in
+    tests/test_decode_program.py."""
+
+    dispatches: int = 0     # runs of the compiled inverse
+    programs: int = 0       # distinct programs built (builder cache misses)
+    # decode-pool threads count concurrently
+    _lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
+
+    def add(self, dispatches: int = 0, programs: int = 0) -> None:
+        with self._lock:
+            self.dispatches += dispatches
+            self.programs += programs
+
+    def reset(self) -> None:
+        with self._lock:
+            self.dispatches = 0
+            self.programs = 0
+
+
+DECODE = DecodeStats()
 
 
 @dataclasses.dataclass

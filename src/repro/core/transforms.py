@@ -90,6 +90,17 @@ def _check_domain(X, spec: FloatSpec, extrema=None):
     return mn, mx
 
 
+def _check_offsets(n_iter: int, spec: FloatSpec, what: str) -> None:
+    """The stored word carries each value's binade offset in its exponent
+    field (data normalized to [1, 2) sits at unbiased exponent 0), so an
+    offset above the format's largest normal exponent cannot be stored."""
+    if n_iter > spec.max_unbiased_exp:
+        raise TransformError(
+            f"{what} lifts values {n_iter} binades, more than {spec.name}'s "
+            f"exponent field holds ({spec.max_unbiased_exp})"
+        )
+
+
 # ===========================================================================
 # §3.1 compact bins
 # ===========================================================================
@@ -175,14 +186,18 @@ def compact_bins_forward(X, n_bins: int, spec: FloatSpec = F64, extrema=None):
     return Xt, meta
 
 
-def compact_bins_inverse(Xt, meta: CompactBinsMeta):
+def _cb_inverse(Xt, shifts, thresholds):
+    """§3.1 inverse on arrays, traceable: the public inverse and the
+    decode program (core/pipeline.py) share it."""
     Xt = _as_i64(Xt)
-    thr = jnp.asarray(meta.thresholds, jnp.int64)
-    shifts = jnp.asarray(meta.shifts, jnp.int64)
-    bin_id = jnp.searchsorted(thr, Xt, side="right") if len(meta.thresholds) else (
-        jnp.zeros(Xt.shape, jnp.int64)
-    )
+    bin_id = (jnp.searchsorted(thresholds, Xt, side="right")
+              if thresholds.shape[0] else jnp.zeros(Xt.shape, jnp.int64))
     return Xt - shifts[bin_id]
+
+
+def compact_bins_inverse(Xt, meta: CompactBinsMeta):
+    return _cb_inverse(Xt, jnp.asarray(meta.shifts, jnp.int64),
+                       jnp.asarray(meta.thresholds, jnp.int64))
 
 
 # ===========================================================================
@@ -266,6 +281,7 @@ def multiply_shift_forward(X, D: int, max_iter: int = 4096, spec: FloatSpec = F6
             f"multiply&shift did not converge in {max_iter} iterations (D={D})"
         )
     n_iter = int(off.max())
+    _check_offsets(n_iter, spec, f"multiply&shift (D={D})")
     meta = MultiplyShiftMeta(e_star=0, D=D, x_max=x_max, n_iter=n_iter)
     return Xf, off, meta
 
@@ -283,13 +299,16 @@ def _ms_inv_loop(Xt, off, a1, a_const, n_iter):
     return Xr
 
 
-def multiply_shift_inverse(Xt, offsets, meta: MultiplyShiftMeta, spec: FloatSpec = F64):
-    Xt = _as_i64(Xt)
-    off = jnp.asarray(offsets, jnp.int32)
+def _ms_inverse_schedule(meta: MultiplyShiftMeta, spec: FloatSpec):
+    """Host half of the §3.2 inverse: the arguments after (Xt, off) of
+    :func:`_ms_inv_loop`, replayed from the metadata."""
     a1, a_const, _ = _ms_schedule(meta.D, meta.x_max, spec)
-    return _ms_inv_loop(
-        Xt, off, jnp.int64(a1), jnp.int64(a_const), jnp.int32(meta.n_iter)
-    )
+    return np.int64(a1), np.int64(a_const), np.int32(meta.n_iter)
+
+
+def multiply_shift_inverse(Xt, offsets, meta: MultiplyShiftMeta, spec: FloatSpec = F64):
+    return _ms_inv_loop(_as_i64(Xt), jnp.asarray(offsets, jnp.int32),
+                        *_ms_inverse_schedule(meta, spec))
 
 
 # ===========================================================================
@@ -405,12 +424,13 @@ def shift_separate_forward(X, D: int, max_iter: int = 64, spec: FloatSpec = F64,
             f"shift&separate did not converge (D={D}); paper plateau regime"
         )
     n_iter = int(max_off)
+    _check_offsets(n_iter, spec, f"shift&separate (D={D})")
     meta = ShiftSeparateMeta(e_star=0, D=D, x_min=x_min, x_max=x_max, n_iter=n_iter)
     return Xf, off, meta
 
 
 @jax.jit
-def _ss_inv_loop(Xt, off, Ae, Ao, T, its):
+def _ss_inv_loop(Xt, off, a_align, Ae, Ao, T, its):
     def step(carry, a):
         X, offc = carry
         ae, ao, t, it = a
@@ -420,24 +440,27 @@ def _ss_inv_loop(Xt, off, Ae, Ao, T, its):
         return (jnp.where(sel, Xprev, X), jnp.where(sel, offc - 1, offc)), None
 
     (Xr, _), _ = lax.scan(step, (Xt, off), (Ae, Ao, T, its))
-    return Xr
+    return Xr - a_align
+
+
+def _ss_inverse_schedule(meta: ShiftSeparateMeta, spec: FloatSpec):
+    """Host half of the §3.3 inverse: the arguments after (Xt, off) of
+    :func:`_ss_inv_loop`, the forward's schedule replayed from the metadata
+    from iteration ``n_iter`` down to 1."""
+    a_align, _, sched = _ss_schedule(meta.D, meta.x_min, meta.x_max, meta.n_iter, spec)
+    steps = sched[: meta.n_iter][::-1]
+    return (
+        np.int64(a_align),
+        np.asarray([s[0] for s in steps], np.int64),
+        np.asarray([s[1] for s in steps], np.int64),
+        np.asarray([s[2] for s in steps], np.int64),
+        np.arange(meta.n_iter, 0, -1, dtype=np.int32),
+    )
 
 
 def shift_separate_inverse(Xt, offsets, meta: ShiftSeparateMeta, spec: FloatSpec = F64):
-    Xt = _as_i64(Xt)
-    off = jnp.asarray(offsets, jnp.int32)
-    a_align, _, sched = _ss_schedule(meta.D, meta.x_min, meta.x_max, meta.n_iter, spec)
-    if meta.n_iter:
-        steps = sched[: meta.n_iter][::-1]            # iteration n_iter .. 1
-        Xt = _ss_inv_loop(
-            Xt,
-            off,
-            jnp.asarray([s[0] for s in steps], jnp.int64),
-            jnp.asarray([s[1] for s in steps], jnp.int64),
-            jnp.asarray([s[2] for s in steps], jnp.int64),
-            jnp.arange(meta.n_iter, 0, -1, dtype=jnp.int32),
-        )
-    return Xt - jnp.int64(a_align)
+    return _ss_inv_loop(_as_i64(Xt), jnp.asarray(offsets, jnp.int32),
+                        *_ss_inverse_schedule(meta, spec))
 
 
 # ===========================================================================
@@ -509,15 +532,21 @@ def shift_save_even_forward(X, D: int, spec: FloatSpec = F64, extrema=None):
     return Y, meta
 
 
-def shift_save_even_inverse(Yt, meta: ShiftSaveEvenMeta, spec: FloatSpec = F64):
-    l = spec.man_bits
+def _sse_inverse(Yt, chunk_ids, evenness, x_min, D, l: int):
+    """§3.4 inverse on arrays, traceable (``x_min`` and ``D`` may be traced
+    scalars): the public inverse and the decode program share it."""
     Y2 = _as_i64(Yt) << 1
-    j = jnp.asarray(meta.chunk_ids, jnp.int64)
-    w_eff = (jnp.int64(1) << (l + 1 - meta.D)) - 2
-    a_base = (jnp.int64(1) << (l + 1)) - meta.x_min - j * w_eff
+    j = _as_i64(chunk_ids)
+    w_eff = (jnp.int64(1) << (l + 1 - D)) - 2
+    a_base = (jnp.int64(1) << (l + 1)) - x_min - j * w_eff
     a_even = a_base + (a_base & 1)
-    A = a_even + jnp.asarray(meta.evenness, jnp.int64)
+    A = a_even + _as_i64(evenness)
     return Y2 - A
+
+
+def shift_save_even_inverse(Yt, meta: ShiftSaveEvenMeta, spec: FloatSpec = F64):
+    return _sse_inverse(Yt, meta.chunk_ids, meta.evenness, meta.x_min,
+                        meta.D, spec.man_bits)
 
 
 # ===========================================================================

@@ -201,3 +201,12 @@ def test_device_stages_carry_stable_scope_names():
                                spec=P.SPECS["f64"],
                                plan=(cand[1],)).as_text(debug_info=True)
     assert "grid_score" in _scopes(text, "_grid_score")
+
+    enc = P.apply_transform(_values(13, n), "shift_save_even", {"D": 16})
+    args = P._inverse_args(enc.method, enc.meta, P.SPECS["f64"], n, n)
+    run_inverse = P._inverse_program(
+        enc.method, "f64", n, tuple((a.shape, a.dtype.str) for a in args))
+    text = run_inverse.lower(np.zeros(n, np.uint64), np.zeros(n, np.int32),
+                             np.zeros(n, np.uint8), *args).as_text(
+                                 debug_info=True)
+    assert {"inverse", "denormalize"} <= _scopes(text, "run_inverse")

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import pipeline as P
 from repro.core import scoring as S
+from repro.core import transforms as T
 from repro.core.transforms import TransformError
 from repro.data import gas_turbine_emissions
 from repro.kernels.rans import kernel as K
@@ -98,6 +99,34 @@ def test_fused_shift_save_even_compiles(one_chip, tpu_trace, spec_name):
                             CHUNK, n_bytes, steps, lanes)
     X = jax.ShapeDtypeStruct((CHUNK,), jnp.int64, sharding=one_chip)
     prog.lower(X).compile()
+
+
+@pytest.mark.parametrize("method", ["shift_save_even", "compact_bins"])
+@pytest.mark.parametrize("spec_name", ["f64", "f32", "bf16"])
+def test_inverse_program_compiles(one_chip, tpu_trace, spec_name, method):
+    """The decode program of a served chunk, with the arguments
+    ``pipeline.decode`` passes it: integer words in and out, so no
+    f64<->u64 bitcast reaches the chip's compiler."""
+    spec = P.SPECS[spec_name]
+    meta = {
+        "shift_save_even": T.ShiftSaveEvenMeta(
+            e_star=0, D=6, x_min=1 << spec.man_bits, n_chunks=1,
+            chunk_ids=np.zeros(CHUNK, np.int64),
+            evenness=np.zeros(CHUNK, np.uint8)),
+        "compact_bins": T.CompactBinsMeta(
+            e_star=0, shifts=np.zeros(16, np.int64),
+            thresholds=np.zeros(15, np.int64)),
+    }[method]
+    args = P._inverse_args(method, meta, spec, CHUNK, CHUNK)
+    prog = P._inverse_program(method, spec_name, CHUNK,
+                              tuple((a.shape, a.dtype.str) for a in args))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    prog.lower(sds((CHUNK,), spec.uint_dtype), sds((CHUNK,), jnp.int32),
+               sds((CHUNK,), jnp.uint8),
+               *(sds(a.shape, a.dtype) for a in args)).compile()
 
 
 def test_rans_scans_compile(one_chip, tpu_trace):

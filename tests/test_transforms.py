@@ -5,7 +5,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import transforms as T
-from repro.core.float_bits import F32, F64
+from repro.core.float_bits import F16, F32, F64
 from repro.core import pipeline
 
 L = F64.man_bits
@@ -91,6 +91,20 @@ def test_multiply_shift_nonconvergence_raises(rng):
     X = rand_sig(2000, rng)  # full binade, high D -> ~2^10 iters needed
     with pytest.raises(T.TransformError):
         T.multiply_shift_forward(X, 10, max_iter=32)
+
+
+def test_offsets_past_the_exponent_field_raise():
+    """A stored word carries its binade offset in the exponent field, which
+    holds offsets up to 15 in f16: multiply&shift lifting values further is
+    rejected, and auto-selection picks a transform that decodes bitwise."""
+    from repro.data import gas_turbine_emissions
+
+    x = gas_turbine_emissions(20000).astype(np.float16)
+    with pytest.raises(T.TransformError, match="exponent field"):
+        T.multiply_shift_forward(pipeline._prepare(x).X, 8, spec=F16)
+    enc = pipeline.encode(x)
+    assert np.array_equal(pipeline.decode(enc).view(np.uint16),
+                          x.view(np.uint16))
 
 
 def test_multiply_shift_binade_climb(rng):
