@@ -164,6 +164,32 @@ def _meta_bytes(meta) -> int:
     return -(-meta.nbits() // 8) if meta is not None else 16
 
 
+# the classic path pads its significands to the rANS scans' length ladder,
+# from a writer's default chunk up, so a family compiles one set of
+# programs for every chunk of a default writer (its tail chunks included)
+# and O(log) beyond; the pad repeats the chunk's last value, which leaves
+# these families' elementwise steps and min/max/any reductions unchanged.
+# Compact bins is not padded: copies of a value change its gap ranking.
+CLASSIC_MIN_ELEMS = 1 << 16
+_PAD_INVARIANT = frozenset({"multiply_shift", "shift_separate",
+                            "shift_save_even"})
+
+
+def _classic_pad(name: str, X):
+    """``X`` padded to its classic-path length (a device array either
+    way).  The pad is made on the host: a device-side pad would itself
+    compile once per input length."""
+    from ..kernels.rans.kernel import bucket_steps
+
+    n = int(X.shape[0])
+    n_pad = bucket_steps(n, floor=CLASSIC_MIN_ELEMS)
+    if name not in _PAD_INVARIANT or n_pad == n:
+        return X
+    with obs.span("encode.device_wait"):
+        xh = np.asarray(X)
+    return jnp.asarray(np.concatenate([xh, np.full(n_pad - n, xh[-1])]))
+
+
 @obs.span("encode.classic")
 def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
     """Run candidate `name` forward on the full significand array, verify the
@@ -171,11 +197,13 @@ def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
     device round-trip.  Returns None if the round-trip fails; raises
     TransformError if the transform's domain conditions reject the data."""
     fwd, inv = T.TRANSFORMS[name]
-    Xt, off, meta = fwd(X, spec=spec, **p)
     n = int(X.shape[0])
+    X = _classic_pad(name, X)
+    n_pad = int(X.shape[0])
+    Xt, off, meta = fwd(X, spec=spec, **p)
     ok = jnp.bool_(True)
-    for s in range(0, n, chunk_elems):
-        e = min(s + chunk_elems, n)
+    for s in range(0, n_pad, chunk_elems):
+        e = min(s + chunk_elems, n_pad)
         Xr = inv(Xt[s:e], off[s:e], _slice_meta(meta, s, e), spec=spec)
         ok = ok & jnp.all(Xr == X[s:e])
     vals = significand_to_bits(Xt, off.astype(jnp.int32), spec)
@@ -183,7 +211,7 @@ def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
         vals_np, ok_np = jax.device_get((vals, ok))
     if not bool(ok_np):
         return None
-    return vals_np.view(spec.float_dtype), meta
+    return vals_np[:n].view(spec.float_dtype), _slice_meta(meta, 0, n)
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +224,10 @@ def _apply_and_verify(name, p, X, spec, chunk_elems=DEFAULT_CHUNK_ELEMS):
 # compact_bins (bin schedule from the in-graph sort).  multiply_shift /
 # shift_separate derive their addend schedules on host from concrete
 # extrema, so they ship through the classic path — a PHASE2 fallback.
+# Phase 1 picks them only where their records are the smallest: on model
+# weights drawn as models/common.py draws them, in f64, f32, bf16 and f16,
+# identity's record is (tests/test_selection_dtypes.py), and on sensor
+# archives shift&save-evenness's is, so a fallback should be rare.
 FUSED_FAMILIES = ("identity", "shift_save_even", "compact_bins")
 # below this many payload bytes the scan's fixed dispatch + compile cost
 # beats the win; the classic host path is used (not counted as a fallback)
@@ -221,19 +253,22 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
     p = dict(pkey)
     l = spec.man_bits
 
-    def entropy(byte_stream):
-        b = byte_stream.astype(jnp.int32)
+    def entropy(padded, n):
+        """``padded``: the ``n`` stream bytes, zero-padded to ``steps *
+        lanes``."""
+        b = padded.astype(jnp.int32)
         with jax.named_scope("histogram"):
-            hist = jnp.bincount(b, length=256)
+            # the padding's zeros are no symbols of the stream
+            hist = jnp.bincount(b, length=256) - jnp.where(
+                jnp.arange(256) == 0, b.shape[0] - n, 0)
             freq = K.quantize_freqs_dev(hist).astype(jnp.int32)
             cum = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                    jnp.cumsum(freq)[:-1]])
-        sym = jnp.pad(b, (0, steps * lanes - n_bytes)).reshape(steps, lanes)
+        sym = b.reshape(steps, lanes)
 
         def step(x, xs):
             t, s = xs
-            return K.encode_scan_body(x, t, s, jnp.int32(n_bytes), freq,
-                                      cum, lanes)
+            return K.encode_scan_body(x, t, s, n, freq, cum, lanes)
 
         with jax.named_scope("lane_scan"):
             x, (b0, b1, e0, e1) = jax.lax.scan(
@@ -242,14 +277,18 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             )
         return freq, b0, b1, e0, e1, x
 
-    def val_bytes(vals):
+    def val_entropy(vals):
         with jax.named_scope("pack"):
-            return jax.lax.bitcast_convert_type(vals, jnp.uint8).reshape(-1)
+            b = jax.lax.bitcast_convert_type(vals, jnp.uint8).reshape(-1)
+        return entropy(jnp.pad(b, (0, steps * lanes - n_bytes)),
+                       jnp.int32(n_bytes))
 
     if method == "identity":
+        # the length is an argument: every length of one step bucket shares
+        # the program
         @jax.jit
-        def run_id(raw):
-            return (jnp.bool_(True),) + entropy(jnp.asarray(raw, jnp.uint8))
+        def run_id(padded, n):
+            return (jnp.bool_(True),) + entropy(padded, n)
 
         return run_id
 
@@ -274,8 +313,7 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             with jax.named_scope("pack"):
                 vals = significand_to_bits(Y, jnp.ones(Y.shape, jnp.int32),
                                            spec)
-            return (ok, vals) + entropy(val_bytes(vals)) + (x_min, j, parity,
-                                                            j_max)
+            return (ok, vals) + val_entropy(vals) + (x_min, j, parity, j_max)
 
         return run_sse
 
@@ -298,11 +336,19 @@ def _fused_program(method: str, pkey: tuple, spec_name: str, n_active: int,
             with jax.named_scope("pack"):
                 vals = significand_to_bits(
                     Xt, jnp.zeros(Xt.shape, jnp.int32), spec)
-            return (ok, vals) + entropy(val_bytes(vals)) + (shifts, thr)
+            return (ok, vals) + val_entropy(vals) + (shifts, thr)
 
         return run_cb
 
     return None
+
+
+def _fell_back(prep: "_Prepared") -> None:
+    """Count a chunk that the classic host path encoded in the fused
+    path's stead.  Below ``FUSED_MIN_BYTES`` the classic path is the
+    design for every method, identity's included, so it is not counted."""
+    if prep.n_active * (prep.spec.width // 8) >= FUSED_MIN_BYTES:
+        S.PHASE2.fallbacks += 1
 
 
 @obs.span("encode.frame")
@@ -330,9 +376,11 @@ def _fused_identity(xf: np.ndarray, shape, spec_name: str) -> Encoded | None:
     if n_bytes < FUSED_MIN_BYTES:
         return None
     lanes, steps = _fused_geometry(n_bytes)
-    prog = _fused_program("identity", (), spec_name, 0, n_bytes, steps, lanes)
+    prog = _fused_program("identity", (), spec_name, 0, 0, steps, lanes)
+    padded = np.zeros(steps * lanes, np.uint8)
+    padded[:n_bytes] = np.ascontiguousarray(xf).view(np.uint8)
     S.PHASE2.dispatches += 1
-    out = prog(np.ascontiguousarray(xf).view(np.uint8))
+    out = prog(padded, np.int32(n_bytes))
     with obs.span("encode.device_wait"):
         out = jax.device_get(out)
     S.PHASE2.device_gets += 1
@@ -405,11 +453,11 @@ def _fused_encode(prep: "_Prepared", name: str, p: dict) -> Encoded | None:
 # cached by a digest of the exact strided sample plus every knob that shapes
 # the plan; a hit skips phase 1 entirely.  Correctness is unaffected:
 # whatever plan comes out, phase 2 still apply+verifies every shipped chunk.
-# Direct `select_method` calls stay uncached unless the caller opts in, so
-# the PHASE1 counter contracts (tests + CI `_counts`) keep their exact
-# meaning.  The store itself is a locked LRU (`core.plans.PlanStore`): a hit
-# refreshes recency — a hot key survives any number of cold inserts — and
-# concurrent encoders (threaded checkpoint save/restore) mutate it safely.
+# Direct `select_method` calls stay uncached, so the PHASE1 counter
+# contracts (tests + CI `_counts`) keep their exact meaning.  The store
+# itself is a locked LRU (`core.plans.PlanStore`): a hit refreshes recency
+# — a hot key survives any number of cold inserts — and concurrent
+# encoders (threaded checkpoint save/restore) mutate it safely.
 # ---------------------------------------------------------------------------
 
 _PLAN_CACHE = plans.PlanStore(max_items=128)
@@ -460,7 +508,9 @@ class _Prepared:
 
     def pack_common(self):
         """Normalization metadata (exponents/signs/passthrough), packed
-        lazily and once — only a shipping non-identity candidate pays."""
+        lazily and once.  Every ranking pays the pack, identity winning or
+        not (phase 1 prices the non-identity candidates' shared streams
+        with it); a shipping non-identity candidate reuses it."""
         if not self._packed:
             from ..compression.bitplane import compress_int_stream
 
@@ -519,6 +569,19 @@ def _prepare(x, spec: FloatSpec | None = None) -> _Prepared:
 # layered primitives
 # ---------------------------------------------------------------------------
 
+def _ships(fn):
+    """Count each :class:`Encoded` that ``fn`` returns, by method, in
+    ``scoring.PHASE2.shipped``."""
+    @functools.wraps(fn)
+    def counted(*a, **k) -> Encoded:
+        enc = fn(*a, **k)
+        S.PHASE2.shipped[enc.method] += 1
+        return enc
+
+    return counted
+
+
+@_ships
 def apply_transform(
     x,
     method: str,
@@ -561,7 +624,7 @@ def apply_transform(
         enc = _fused_encode(prep, method, params or {})
         if enc is not None:
             return enc
-        S.PHASE2.fallbacks += 1
+        _fell_back(prep)
     applied = _apply_and_verify(method, params or {}, prep.X, prep.spec,
                                 chunk_elems)
     if applied is None:
@@ -580,40 +643,23 @@ def select_method(
     top_k: int = DEFAULT_TOP_K,
     engine: str | None = None,
     backend: str | None = None,
-    use_cache: bool = False,
 ) -> tuple[str, dict]:
-    """Phase-1 primitive: rank candidates on ``x`` (typically a strided
-    sample) and return the winning ``(method, params)`` without applying it
-    to anything.  Streaming writers call this once, then stream every chunk
-    through :func:`apply_transform`.
+    """Phase-1 primitive: rank candidates on ``x`` and return the winning
+    ``(method, params)`` without applying it to anything.  Uncached, so the
+    PHASE1 dispatch-counter contracts stay exact.
 
     ``backend`` names the byte-stream compressor the caller will feed
     (container writers pass theirs): ``"rans"`` switches the analytic
     ranking to the rANS size model (pooled byte entropy + frequency-table
     overhead, zero extra dispatches — it falls out of the same scoregrid
-    histogram) and re-scores finalists with the real rANS coder.
-
-    ``use_cache=True`` consults the content-keyed selection plan cache
-    (streaming writers probing identical samples skip re-selection); the
-    default keeps this primitive uncached so the PHASE1 dispatch-counter
-    contracts stay exact."""
+    histogram) and re-scores finalists with the real rANS coder."""
     prep = _prepare(x, spec)
     if prep.n_active == 0:
         return "identity", {}
-    key = None
-    if use_cache and size_fn is None:
-        key = _plan_key(prep.xf, prep.n, prep.spec.name, candidates,
-                        sample_elems, top_k, engine, backend)
-        cached = _PLAN_CACHE.get(key)
-        if cached:
-            name, p = cached[0]
-            return name, dict(p)
     ranked, _first = _rank_candidates(prep, candidates, size_fn,
                                       sample_elems, top_k, engine, backend)
     if not ranked:
         raise T.TransformError("no feasible transform candidate")
-    if key is not None:
-        _PLAN_CACHE.put(key, list(ranked))
     name, p = ranked[0]
     return name, dict(p)
 
@@ -697,42 +743,50 @@ def _rank_candidates(prep: _Prepared, candidates, size_fn, sample_elems,
     engine = engine or default_engine()
     if engine not in _ENGINES:
         raise ValueError(f"unknown scoring engine {engine!r}; use {_ENGINES}")
-    analytic = size_fn is None
-    has_identity = any(n_ == "identity" for n_, _ in candidates)
-    if analytic:
-        if backend_hint == "rans":
-            from ..kernels.rans import ops as _rans_ops
-
-            size_fn = lambda b: len(_rans_ops.compress(b))
-        else:
-            size_fn = lambda b: len(zlib.compress(b, 6))
-        from ..compression.bitplane import compress_int_stream
-
-        # selection-time estimate of the shared normalization metadata:
-        # pack a strided sample of exponents/signs and scale up (it is a
-        # constant added to every non-identity candidate, so only its
-        # magnitude vs identity matters, not its exact value)
-        exps_s = _strided(prep.exps_np, sample_elems)
-        sc = prep.exps_np.shape[0] / max(exps_s.shape[0], 1)
-        pass_s = _strided(prep.pass_mask, sample_elems)
-        common_est = (
-            len(compress_int_stream(exps_s))
-            + len(_pack_z(_strided(prep.signs_np, sample_elems)))
-        ) * sc + len(_pack_z(pass_s)) * (
-            prep.pass_mask.shape[0] / max(pass_s.shape[0], 1)
-        )
+    # the shared normalization streams (exponents, signs, passthrough mask)
+    # cost every non-identity candidate the same; they are packed exactly,
+    # with the record's own coders, since their packed width follows the
+    # whole stream's range, which a sample understates.  The pack is cached
+    # on ``prep``, so a non-identity winner ships it without packing again.
+    common_meta = sum(map(len, prep.pack_common()))
+    if size_fn is None:
+        has_identity = any(n_ == "identity" for n_, _ in candidates)
         ranked = _select_analytic(
-            prep.xf, prep.finite, prep.X, prep.spec, candidates, size_fn,
-            common_est, sample_elems, top_k, has_identity, engine=engine,
-            backend_hint=backend_hint,
+            prep.xf, prep.finite, prep.X, prep.spec, candidates,
+            _sampled_size(backend_hint), common_meta, sample_elems, top_k,
+            has_identity, engine=engine, backend_hint=backend_hint,
         )
         return ranked, None
-    exponents_z, signs_z, passthrough_z = prep.pack_common()
-    common_meta = len(exponents_z) + len(signs_z) + len(passthrough_z)
     return _select_exact(
         prep.xf, prep.finite, prep.X, prep.spec, candidates, size_fn,
         common_meta,
     )
+
+
+def _sampled_size(backend: str | None) -> Callable[..., float]:
+    """``size(b, scale)``: the coded bytes of a stream ``scale`` times as
+    long as the sample ``b`` and alike in its statistics, as the record's
+    payload coder writes it.  The coder's body grows with the stream; a
+    rANS frame (header, frequency table, per-lane length words and state
+    flushes) is written once, at the lane count of the whole stream, so it
+    is not scaled.  ``framed=False`` gives the body alone, for bytes that
+    share another stream's frame."""
+    if backend != "rans":
+        return lambda b, scale, framed=True: len(zlib.compress(b, 6)) * scale
+    from ..kernels.rans import ops as rans_ops, ref as R
+
+    lanes = rans_ops.default_lanes()
+
+    def frame(n_bytes: int, syms: int) -> int:
+        return R.frame_overhead_bytes(syms, R.clamp_lanes(lanes, n_bytes))
+
+    def size(b: bytes, scale: float, framed: bool = True) -> float:
+        syms = int(np.count_nonzero(
+            np.bincount(np.frombuffer(b, np.uint8), minlength=256)))
+        body = (len(rans_ops.compress(b)) - frame(len(b), syms)) * scale
+        return body + frame(round(len(b) * scale), syms) if framed else body
+
+    return size
 
 
 def serialize_chunk(enc: Encoded, backend: str = "zlib") -> bytes:
@@ -813,7 +867,13 @@ def _encode_full(
         # explicit method: phase 2 only (identity and all-passthrough
         # inputs short-circuit inside apply_transform)
         return apply_transform(x, method, params, spec, chunk_elems, backend)
+    return _encode_auto(x, candidates, size_fn, spec, sample_elems, top_k,
+                        chunk_elems, engine, backend)
 
+
+@_ships
+def _encode_auto(x, candidates, size_fn, spec, sample_elems, top_k,
+                 chunk_elems, engine, backend) -> Encoded:
     prep = _prepare(x, spec)
     if prep.n_active == 0:
         # nothing to transform: pure passthrough
@@ -868,7 +928,7 @@ def _encode_full(
         if applied is None:
             continue  # failed round-trip: rejected, never shipped
         if backend == "rans":
-            S.PHASE2.fallbacks += 1
+            _fell_back(prep)
         return prep.finish(name, p, *applied)
     if has_identity:
         return prep.identity_encoded()
@@ -938,7 +998,10 @@ def _select_analytic(
 ):
     """Analytic sample-select: rank candidates by the fused plane-stats size
     estimate; re-score the top finalists (+ identity) with the real
-    compressor.  Returns candidate (name, params) in preference order."""
+    compressor.  Returns candidate (name, params) in preference order.
+
+    ``size_fn(b, scale, framed=True)`` is :func:`_sampled_size`'s model of
+    the payload coder: the coded bytes of a stream ``scale`` times ``b``."""
     n_active = int(X.shape[0])
     Xs = _strided(X, sample_elems)
     n_s = int(Xs.shape[0])
@@ -1059,8 +1122,8 @@ def _select_analytic(
     if has_identity:
         xs_all = _strided(xf, sample_elems)
         exact.append(
-            (size_fn(np.ascontiguousarray(xs_all).tobytes())
-             * (xf.shape[0] / xs_all.shape[0]) + 16, "identity", {})
+            (size_fn(np.ascontiguousarray(xs_all).tobytes(),
+                     xf.shape[0] / xs_all.shape[0]) + 16, "identity", {})
         )
     # passthrough bytes ship verbatim in every non-identity candidate's data
     # stream too (seed scored xf with data[finite]=vals); a constant term,
@@ -1068,9 +1131,8 @@ def _select_analytic(
     xp = xf[~finite]
     if xp.size:
         xps = _strided(xp, sample_elems)
-        pass_cost = (
-            size_fn(np.ascontiguousarray(xps).tobytes()) * (xp.size / xps.size)
-        )
+        pass_cost = size_fn(np.ascontiguousarray(xps).tobytes(),
+                            xp.size / xps.size, framed=False)
     else:
         pass_cost = 0.0
     for s in finalists:
@@ -1094,7 +1156,7 @@ def _select_analytic(
                 data_bytes = np.asarray(vals).tobytes()
             meta_cost = _scaled_meta_bytes(meta, scale)
         exact.append(
-            (size_fn(data_bytes) * scale + pass_cost + meta_cost
+            (size_fn(data_bytes, scale) + pass_cost + meta_cost
              + common_meta, name, p)
         )
     exact.sort(key=lambda t: t[0])

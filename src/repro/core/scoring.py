@@ -39,6 +39,7 @@ CI bench gate can pin the single-dispatch property instead of trusting it.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import threading
@@ -107,11 +108,15 @@ class Phase2Stats:
     # encodes that could not fuse (transform needs host-side scheduling,
     # non-rans backend, ...) and took the eager multi-dispatch path instead
     fallbacks: int = 0
+    # chunks phase 2 shipped, by method name, whichever path encoded them
+    shipped: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
 
     def reset(self) -> None:
         self.dispatches = 0
         self.device_gets = 0
         self.fallbacks = 0
+        self.shipped.clear()
 
 
 PHASE2 = Phase2Stats()
@@ -417,40 +422,58 @@ def _plan_candidate(name: str, p: dict, spec: FloatSpec, extrema,
     operands, ``("defer",)`` when the candidate is valid on the full array
     but cannot be evaluated on the sample (e.g. compact_bins with more bins
     than sample elements), or ``("generic",)`` for transforms without a
-    fused builder.  Raises TransformError for infeasibility on the FULL
-    array."""
+    fused builder.  Raises TransformError where the format rules the
+    candidate out (its parameter against the float spec, or the array's
+    size).  Where only the sample's span does, it returns ``("idle", entry,
+    dyn, error)``: the row keeps its place on the grid with inert operands
+    and is dropped after the fetch, so the grid's program depends on the
+    candidates, the spec and the sample's length alone, never on the data."""
     from . import transforms as T
 
     l = spec.man_bits
     x_min, x_max = int(extrema[0]), int(extrema[1])
+    if name in ("multiply_shift", "shift_separate") and not (
+            1 <= int(p["D"]) <= l - 2):
+        raise T.TransformError(f"{name} needs 1 <= D <= {l - 2}")
     if name == "shift_save_even":
         w_eff = T._sse_feasible(int(p["D"]), spec)
         return ("grid", ("sse", w_eff, 1 << (l + 1)), ())
     if name == "multiply_shift":
         max_iter = int(p.get("max_iter", 4096))
-        a1, a_const, thresh = T._ms_feasible(
-            int(p["D"]), x_min, x_max, max_iter, spec
-        )
+        entry = ("ms", max_iter)
+        try:
+            a1, a_const, thresh = T._ms_feasible(
+                int(p["D"]), x_min, x_max, max_iter, spec
+            )
+        except T.TransformError as e:
+            # a zero threshold captures every value on the first step
+            return ("idle", entry, (np.int64(0),) * 3, e)
         # plain numpy scalars go straight into the jit call — no eager
         # device_put dispatches (they cost ~0.3ms each, x4 per candidate)
-        return ("grid", ("ms", max_iter),
+        return ("grid", entry,
                 (np.int64(a1), np.int64(a_const), np.int64(thresh)))
     if name == "shift_separate":
         max_iter = int(p.get("max_iter", 64))
-        a_align, cap, sched = T._ss_feasible(
-            int(p["D"]), x_min, x_max, max_iter, spec
-        )
-        ok = [(ae, ao) for ae, ao, _t, is_ok in sched if is_ok]
+        entry = ("ss", max_iter)
         # schedule padded to the STATIC max_iter with disabled tail steps:
         # its data-dependent length must not leak into the grid plan (a
         # distinct plan re-compiles the whole stacked jit)
         Ae = np.zeros(max_iter, np.int64)
         Ao = np.zeros(max_iter, np.int64)
         enabled = np.zeros(max_iter, bool)
+        try:
+            a_align, cap, sched = T._ss_feasible(
+                int(p["D"]), x_min, x_max, max_iter, spec
+            )
+        except T.TransformError as e:
+            # every step disabled: the scan leaves the sample as it is
+            return ("idle", entry, (np.int64(0), Ae, Ao, enabled,
+                                    np.int64(0)), e)
+        ok = [(ae, ao) for ae, ao, _t, is_ok in sched if is_ok]
         Ae[: len(ok)] = [a for a, _ in ok]
         Ao[: len(ok)] = [a for _, a in ok]
         enabled[: len(ok)] = True
-        return ("grid", ("ss", max_iter),
+        return ("grid", entry,
                 (np.int64(a_align), Ae, Ao, enabled, np.int64(cap)))
     if name == "compact_bins":
         k = int(p["n_bins"])
@@ -483,6 +506,8 @@ def score_candidate(name: str, p: dict, X, spec: FloatSpec, extrema,
         return "defer"
     if plan[0] == "generic":
         return None
+    if plan[0] == "idle":
+        raise plan[3]
     entry, dyn = plan[1], plan[2]
     fam = entry[0]
     PHASE1.dispatches += 1
@@ -573,7 +598,7 @@ def score_candidates_stacked(candidates, Xs, spec: FloatSpec, extrema,
     order, plus the candidates that must be tried unscored in phase 2."""
     from . import transforms as T
 
-    entries: list[tuple] = []          # ("grid", name, p) | ("generic", score)
+    entries: list[tuple] = []  # ("grid"|"idle", name, p) | ("generic", score)
     plan, dyn = [], []
     deferred: list[tuple[str, dict]] = []
     n_sample = int(Xs.shape[0])
@@ -595,7 +620,7 @@ def score_candidates_stacked(candidates, Xs, spec: FloatSpec, extrema,
         else:
             plan.append(cand[1])
             dyn.append(cand[2])
-            entries.append(("grid", name, p))
+            entries.append((cand[0], name, p))
     pending = [e[1] for e in entries if e[0] == "generic"]
     handles = [s._dev for s in pending]
     if plan:
@@ -619,7 +644,9 @@ def score_candidates_stacked(candidates, Xs, spec: FloatSpec, extrema,
     scores: list[CandidateScore] = []
     ri = gi = 0
     for e in entries:
-        if e[0] == "grid":
+        if e[0] == "idle":
+            ri += 1              # ruled out by the sample's span: no score
+        elif e[0] == "grid":
             row = mat[ri]
             scores.append(CandidateScore(
                 name=e[1], params=e[2],

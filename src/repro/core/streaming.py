@@ -9,8 +9,8 @@ module is the one shared engine they all ride now:
 * :func:`iter_fixed_chunks` re-chunks an *iterable* of arbitrary-size array
   pieces into the container's fixed chunk geometry while holding at most
   one chunk plus one piece in memory — the spill-free ingestion primitive.
-* :class:`WindowPlanner` is the selection policy as an object: probe once
-  on the first sizeable chunk (exactly the historical writer policy), then
+* :class:`WindowPlanner` is the selection policy as an object: select once
+  on the first sizeable chunk (full auto on that chunk), then
   group the stream into fixed-size **windows** (``REPRO_STREAM_WINDOW_BYTES``)
   and, at each window boundary, compare a PR 8
   :class:`~repro.core.plans.StreamFingerprint` of the stream-now against
@@ -48,8 +48,9 @@ DEFAULT_WINDOW_BYTES = 4 << 20
 DEFAULT_QUEUE_DEPTH = 2
 
 # selection probe geometry (moved here from container/io.py, which
-# re-exports them): arrays at or below the threshold run full auto per
-# chunk; larger streams probe once on a strided sample per window policy
+# re-exports them): chunks at or below the threshold run full auto each;
+# larger streams run it once on their first chunk per window policy, and
+# fingerprint a strided sample of PROBE_ELEMS values for drift
 PROBE_ELEMS = 8192
 PROBE_THRESHOLD = 16384
 
@@ -126,13 +127,14 @@ class WindowPlanner:
       every chunk phase-2-only through ``pipeline.encode_with_plan``;
     * an explicit ``method`` applies it per chunk (identity fallback);
     * ``method="auto"``: chunks at or below ``probe_threshold`` elements run
-      full auto individually; the first larger chunk is probed once
-      (``select_method(use_cache=True)`` on a strided sample) and its pick
-      — plus a :class:`~repro.core.plans.StreamFingerprint` of that sample
-      — becomes the window plan.  Every ``window_bytes`` of subsequent
-      stream, the boundary chunk is fingerprinted and compared:
-      ``drift > REPRO_PLAN_DRIFT`` re-selects (a *drift refresh*), anything
-      else reuses the pick selection-free.
+      full auto individually; the first larger chunk is probed once (full
+      auto on that chunk, whose phase 1 ranks a strided sample) and the
+      method it shipped — plus a
+      :class:`~repro.core.plans.StreamFingerprint` of a ``probe_elems``
+      strided sample — becomes the window plan.  Every ``window_bytes`` of
+      subsequent stream, the boundary chunk is fingerprinted and compared:
+      ``drift > REPRO_PLAN_DRIFT`` re-selects on it (a *drift refresh*),
+      anything else reuses the pick selection-free.
 
     The decision sequence depends only on the chunk sequence (sizes and
     values), so two writers fed the same chunks emit identical records —
@@ -166,43 +168,50 @@ class WindowPlanner:
         self.stats = {"probes": 0, "windows": 0, "reused_windows": 0,
                       "drift_refreshes": 0}
 
-    @obs.span("select.probe")
-    def _select(self, chunk, stat: str, sample=None,
-                fp: plans.StreamFingerprint | None = None) -> None:
-        if sample is None:
-            sample = pipeline._strided(chunk, self._probe_elems)
-        try:
-            self.picked = pipeline.select_method(
-                sample, candidates=self._candidates, spec=self._spec,
-                backend=self._backend, use_cache=True,
-            )
-            self._fp = fp if fp is not None else (
-                plans.StreamFingerprint.from_array(np.asarray(sample))
-            )
-            self.stats[stat] += 1
-        except T.TransformError:
-            # no feasible candidate for this sample: full auto per chunk
-            self.picked = ("auto", None)
-            self._fp = None
+    def _full_auto(self, chunk) -> pipeline.Encoded:
+        return pipeline.encode(chunk, method="auto",
+                               candidates=self._candidates, spec=self._spec,
+                               backend=self._backend)
 
-    def _window_check(self, chunk, nbytes: int) -> None:
+    def _select(self, chunk, stat: str, fp: plans.StreamFingerprint | None
+                = None) -> pipeline.Encoded | None:
+        """Encode ``chunk`` with full selection and make what it shipped
+        the window's pick.  Selection sees the whole chunk, so it ranks the
+        candidates by the records a chunk of this size stores (the packed
+        width of the shared exponent stream follows the whole chunk's
+        range, which a sample understates); the chunk's normalization is
+        shared between ranking and shipping.  None when no candidate is
+        feasible: the chunk then runs full auto like any other."""
+        try:
+            enc = self._full_auto(chunk)
+        except T.TransformError:
+            self.picked, self._fp = ("auto", None), None
+            return None
+        self.picked = (enc.method, dict(enc.params))
+        self._fp = fp if fp is not None else plans.StreamFingerprint.from_array(
+            np.asarray(pipeline._strided(chunk, self._probe_elems)))
+        self.stats[stat] += 1
+        return enc
+
+    def _window_check(self, chunk, nbytes: int) -> pipeline.Encoded | None:
         """Advance the window accounting; at a boundary, fingerprint the
-        boundary chunk and drift-refresh or reuse."""
+        boundary chunk and, on drift, re-select on it (returning its
+        encoding), else reuse the pick."""
         self._window_fill += nbytes
         if self._window_fill < self.window_bytes:
-            return
+            return None
         self._window_fill = 0
         if self._fp is None or int(chunk.size) <= self._probe_threshold:
             # fingerprint-less pick (probe failed) or a tail chunk too
             # small to sample representatively: keep the current pick
-            return
+            return None
         self.stats["windows"] += 1
-        sample = pipeline._strided(chunk, self._probe_elems)
-        fp = plans.StreamFingerprint.from_array(np.asarray(sample))
+        fp = plans.StreamFingerprint.from_array(
+            np.asarray(pipeline._strided(chunk, self._probe_elems)))
         if self._fp.drift(fp) > plans.plan_drift_threshold():
-            self._select(chunk, "drift_refreshes", sample=sample, fp=fp)
-        else:
-            self.stats["reused_windows"] += 1
+            return self._select(chunk, "drift_refreshes", fp=fp)
+        self.stats["reused_windows"] += 1
+        return None
 
     def encode(self, chunk) -> pipeline.Encoded:
         """Encode one chunk under the window policy (always round-trips:
@@ -215,19 +224,19 @@ class WindowPlanner:
         name, prm = self._method, self._params
         if name == "auto":
             size = int(chunk.size)
+            enc = None
             if self.picked is None:
                 if size > self._probe_threshold:
-                    self._select(chunk, "probes")
+                    enc = self._select(chunk, "probes")
                     self._window_fill = size * chunk.dtype.itemsize
             else:
-                self._window_check(chunk, size * chunk.dtype.itemsize)
+                enc = self._window_check(chunk, size * chunk.dtype.itemsize)
+            if enc is not None:
+                return enc
             name, prm = self.picked or ("auto", None)
         try:
             if name == "auto":
-                return pipeline.encode(
-                    chunk, method="auto", candidates=self._candidates,
-                    spec=self._spec, backend=self._backend,
-                )
+                return self._full_auto(chunk)
             return pipeline.apply_transform(chunk, name, prm, spec=self._spec,
                                             backend=self._backend)
         except T.TransformError:

@@ -122,13 +122,13 @@ def test_writer_reader_streaming(tmp_path, backend):
 def test_writer_selection_happens_once(tmp_path, monkeypatch):
     """The streaming contract: one probe, then apply per chunk."""
     calls = {"n": 0}
-    real = pipeline.select_method
+    real = pipeline._rank_candidates
 
     def counting(*a, **k):
         calls["n"] += 1
         return real(*a, **k)
 
-    monkeypatch.setattr(pipeline, "select_method", counting)
+    monkeypatch.setattr(pipeline, "_rank_candidates", counting)
     x = chicago_taxi_fares(100_000)
     with ContainerWriter(tmp_path / "s.fpc", dtype=np.float64) as w:
         for i in range(0, x.size, 20_000):

@@ -125,9 +125,9 @@ def test_family_diverse_finalists():
     spec = spec_for(x)
     y01, e, s = normalize_bits(xf[finite].view(np.uint64), spec)
     X = significand_from_bits(y01, spec)
-    zfn = lambda b: len(zlib.compress(b, 6))
     ranked = pipeline._select_analytic(
-        xf, finite, X, spec, pipeline.DEFAULT_CANDIDATES, zfn, 100.0,
+        xf, finite, X, spec, pipeline.DEFAULT_CANDIDATES,
+        pipeline._sampled_size(None), 100.0,
         pipeline.DEFAULT_SAMPLE_ELEMS, pipeline.DEFAULT_TOP_K,
     )
     # the head (exact-scored finalists + identity) is family-diverse; the

@@ -101,6 +101,18 @@ def test_fused_shift_save_even_compiles(one_chip, tpu_trace, spec_name):
     prog.lower(X).compile()
 
 
+@pytest.mark.parametrize("n_bytes", [4096, CHUNK * 2])
+def test_fused_identity_compiles(one_chip, tpu_trace, n_bytes):
+    """The identity program takes its stream padded to its step bucket and
+    the stream's length as an argument (a 2,048-value bf16 tensor and a
+    whole bf16 chunk)."""
+    lanes, steps = P._fused_geometry(n_bytes)
+    prog = P._fused_program("identity", (), "bf16", 0, 0, steps, lanes)
+    raw = jax.ShapeDtypeStruct((steps * lanes,), jnp.uint8, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    prog.lower(raw, n).compile()
+
+
 @pytest.mark.parametrize("method", ["shift_save_even", "compact_bins"])
 @pytest.mark.parametrize("spec_name", ["f64", "f32", "bf16"])
 def test_inverse_program_compiles(one_chip, tpu_trace, spec_name, method):
